@@ -4,9 +4,11 @@ Each kernel ships as a subpackage:  <name>/<name>.py (pl.pallas_call +
 BlockSpec VMEM tiling), <name>/ops.py (jit'd public wrapper), and
 <name>/ref.py (pure-jnp oracle used by the sweep tests).
 
-On the CPU backend (this container) kernels execute with interpret=True
-(the kernel body runs in Python), which is how correctness is validated;
-on TPU the same pallas_call lowers through Mosaic.
+On the CPU backend kernels execute with interpret=True (the kernel body
+runs in Python), which is how the tests check them; on TPU the same
+pallas_call lowers through Mosaic. Any other backend is an error: a
+kernel silently interpreted on an accelerator would hide that the run
+never used the chip.
 """
 import functools
 
@@ -15,7 +17,9 @@ import jax
 
 @functools.cache
 def on_tpu() -> bool:
-    """Shared backend probe for the jit'd kernel wrappers.
+    """Shared backend probe for the jit'd kernel wrappers: True on TPU
+    (compile through Mosaic), False on CPU (interpret), and a
+    RuntimeError on any other backend.
 
     The backend cannot change within a process, so the probe is cached:
     wrappers decide ``interpret=not on_tpu()`` once instead of calling
@@ -23,7 +27,12 @@ def on_tpu() -> bool:
     every trace. Defined above the subpackage imports so that ops
     modules can ``from repro.kernels import on_tpu`` without a cycle.
     """
-    return jax.default_backend() == "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels here compile for TPU or run interpreted on "
+            f"CPU; the {backend!r} backend is neither")
+    return backend == "tpu"
 
 
 from repro.kernels.flash_attention.ops import flash_attention  # noqa: E402

@@ -10,11 +10,8 @@ from repro.kernels import on_tpu
 from repro.kernels.blendavg.blendavg import blend_params_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("block_n",))
-def blend_params(stacked, omega, *, block_n: int = 2048):
-    """stacked: (L, N) array OR pytree whose leaves have leading dim L.
-    omega (L,) masked blend weights. Returns blended array / pytree."""
-    interpret = not on_tpu()
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+def _blend(stacked, omega, block_n, interpret):
     if isinstance(stacked, jnp.ndarray) or hasattr(stacked, "shape"):
         return blend_params_pallas(stacked, omega, block_n=block_n,
                                    interpret=interpret)
@@ -26,3 +23,9 @@ def blend_params(stacked, omega, *, block_n: int = 2048):
         return out.reshape(leaf.shape[1:])
 
     return jax.tree.map(blend_leaf, stacked)
+
+
+def blend_params(stacked, omega, *, block_n: int = 2048):
+    """stacked: (L, N) array OR pytree whose leaves have leading dim L.
+    omega (L,) masked blend weights. Returns blended array / pytree."""
+    return _blend(stacked, omega, block_n, not on_tpu())

@@ -13,6 +13,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,15 +26,23 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devs)} — the dry-run "
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import/init")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over the real local devices (CPU tests / examples)."""
-    n = len(jax.devices())
-    dp = n // model_parallel
+def make_host_mesh(model_parallel: int = 1, devices=None):
+    """Mesh over the local devices (every one unless ``devices`` narrows
+    it): the trainer's data axis, and the CPU tests and examples.
+
+    Both meshes are ``Auto`` on every axis: the round's ``vmap`` mixes
+    replicated state with data-sharded batches, which an ``Explicit``
+    mesh (``jax.make_mesh``'s default) rejects at trace time.
+    """
+    devs = jax.devices() if devices is None else list(devices)
+    dp = len(devs) // model_parallel
     return jax.make_mesh((dp, model_parallel), ("data", "model"),
-                         devices=jax.devices()[: dp * model_parallel])
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=devs[: dp * model_parallel])
 
 
 def data_axes(mesh) -> tuple:

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import time
+import zlib
 
 import numpy as np
 
@@ -79,7 +80,8 @@ def models_from_checkpoint(ckpt_dir: str, spec, ecfg, step: int | None = None):
 
 def train_models(spec, ecfg, *, rounds: int, clients: int, seed: int):
     """Tiny in-process BlendFL federation — enough training that the
-    served models are real blended artifacts, not random init."""
+    served models are real blended artifacts, not random init. Returns
+    ``(global_models, server_gmv, history)``, one log dict per round."""
     import jax
 
     from repro.core.federation import FedConfig, Federation
@@ -92,10 +94,10 @@ def train_models(spec, ecfg, *, rounds: int, clients: int, seed: int):
                      seed=seed)
     fed = Federation.init(jax.random.PRNGKey(seed), fcfg, spec, ecfg,
                           parts, va)
-    fed.fit()
+    history = fed.fit()
     print(f"trained in-process federation: {clients} clients, "
           f"{rounds} rounds")
-    return fed.global_models, fed.server_gmv
+    return fed.global_models, fed.server_gmv, history
 
 
 def make_requests(spec, mix: str, n: int, *, rows: int, seed: int) -> list:
@@ -105,7 +107,8 @@ def make_requests(spec, mix: str, n: int, *, rows: int, seed: int) -> list:
     from repro.core.inference import InferenceRequest
 
     p_mm, p_a, p_b, p_vfl = MIXES[mix]
-    rng = np.random.default_rng([seed, hash(mix) & 0xFFFF])
+    # zlib.crc32: the same stream in every process (hash() is salted)
+    rng = np.random.default_rng([seed, zlib.crc32(mix.encode()) & 0xFFFF])
     kinds = rng.choice(4, size=n, p=[p_mm, p_a, p_b, p_vfl])
     out = []
     for kind in kinds:
@@ -162,8 +165,9 @@ def selftest(args) -> None:
     spec = make_task(args.task)
     ecfg = EncoderConfig(d_hidden=args.d_hidden, n_layers=args.n_layers,
                          enc_type=args.enc_type)
-    models, gmv = train_models(spec, ecfg, rounds=max(2, args.train_rounds),
-                               clients=args.clients, seed=args.seed)
+    models, gmv, _ = train_models(spec, ecfg,
+                                  rounds=max(2, args.train_rounds),
+                                  clients=args.clients, seed=args.seed)
     engine = build_engine(args, models, gmv, ecfg, spec.kind)
 
     total_bytes = 0
@@ -223,6 +227,10 @@ def main() -> None:
                     help="2 mixes + cache/parity/bytes assertions, then exit")
     args = ap.parse_args()
 
+    from repro.launch.runtime import device_line, use_compile_cache
+
+    use_compile_cache()
+    print(device_line())
     if args.selftest:
         selftest(args)
         return
@@ -237,8 +245,8 @@ def main() -> None:
         models, gmv = models_from_checkpoint(args.ckpt_dir, spec, ecfg,
                                              step=args.step)
     else:
-        models, gmv = train_models(spec, ecfg, rounds=args.train_rounds,
-                                   clients=args.clients, seed=args.seed)
+        models, gmv, _ = train_models(spec, ecfg, rounds=args.train_rounds,
+                                      clients=args.clients, seed=args.seed)
     engine = build_engine(args, models, gmv, ecfg, spec.kind)
 
     for mix in (args.mix or sorted(MIXES)):

@@ -42,6 +42,7 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import (latest_step, read_manifest, restore_checkpoint,
                               save_checkpoint)
@@ -62,6 +63,7 @@ from repro.data.store import ClientStore, write_store
 from repro.data.synthetic import make_task, train_val_test
 from repro.launch import shardings as sh
 from repro.launch.mesh import make_host_mesh
+from repro.launch.runtime import device_line, use_compile_cache
 
 
 def client_arrays(cd: ClientData) -> dict:
@@ -107,11 +109,12 @@ def import_store(args) -> ClientStore:
     return store
 
 
-def build_federation(args) -> tuple:
+def build_federation(args, devices=None) -> tuple:
     """(spec, batcher, round_fn, mesh) for a ragged federation — in-memory
     synthetic by default, out-of-core when ``--store-dir`` names an
     imported ``ClientStore`` (client arrays then stay on disk; only the
-    drawn row subsets are ever materialized)."""
+    drawn row subsets are ever materialized). The client axis shards over
+    ``devices`` (default: every local device)."""
     # static per-round capacities sized to the ragged partition
     n_partial = max(args.rows_cap, 1)
     scenario = None
@@ -181,7 +184,13 @@ def build_federation(args) -> tuple:
             # batch key trace in), WHO attacks each round is data
             attacks=(scenario.has_uplink_attacks()
                      if scenario is not None else False))
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(devices=devices)
+    n_data = mesh.shape["data"]
+    if spec.k_round % n_data:
+        raise ValueError(
+            f"{spec.k_round} clients train per round, which the {n_data}-way "
+            "data axis of the device mesh does not divide: choose --clients "
+            f"(or --n-sampled) as a multiple of {n_data}")
     shard = sh.batch_shardings(mesh, batch_specs(spec, ragged=True))
     if store is not None:
         batcher = FederatedBatcher.from_store(
@@ -193,7 +202,16 @@ def build_federation(args) -> tuple:
             {"val_a": va.x_a, "val_b": va.x_b, "val_y": va.y},
             seed=args.seed, shardings=shard, prefetch=args.prefetch,
             scenario=scenario, n_initial=args.clients)
-    return spec, batcher, jax.jit(make_blendfl_round(spec)), mesh
+    return spec, batcher, jit_round(spec, mesh), mesh
+
+
+def jit_round(spec: ShardedFedSpec, mesh):
+    """The jitted round, its outputs pinned replicated on ``mesh``: on a
+    multi-device mesh GSPMD would otherwise choose other shardings for
+    the returned state, and the next round would compile a second
+    program."""
+    return jax.jit(make_blendfl_round(spec),
+                   out_shardings=NamedSharding(mesh, P()))
 
 
 def place_state(state: dict, mesh) -> dict:
@@ -275,7 +293,7 @@ def run_scenario(args, spec, batcher, round_fn, mesh, start: int, state: dict,
             spec = dataclasses.replace(spec, n_clients=cap)
             batcher.set_spec(spec)
             if cap not in round_fns:
-                round_fns[cap] = jax.jit(make_blendfl_round(spec))
+                round_fns[cap] = jit_round(spec, mesh)
         if ev is not None and ev.leave:
             log(f"round {r}: clients {list(ev.leave)} depart "
                 "(state rows retired, never sampled again)")
@@ -482,7 +500,7 @@ def selftest_resume_scenario(args) -> None:
           f"strategy={getattr(args, 'strategy', 'blendavg')})")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("command", nargs="?", choices=["import"], default=None,
                     help="'import': convert the synthetic partition to an "
@@ -547,8 +565,14 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=1)
     ap.add_argument("--selftest-resume", action="store_true",
                     help="run the killed-and-resumed parity assertion and exit")
-    args = ap.parse_args()
+    return ap
 
+
+def main() -> None:
+    args = build_parser().parse_args()
+
+    use_compile_cache()
+    print(device_line())
     if args.command == "import":
         import_store(args)
         return

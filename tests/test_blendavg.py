@@ -2,7 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.blendavg import blend_trees, blendavg, blendavg_weights, fedavg
 

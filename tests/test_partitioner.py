@@ -1,7 +1,7 @@
 """Data-fragmentation invariants (paper §III-A) — property-based."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partitioner import fragmented_overlap, partition
 from repro.data.synthetic import generate, make_task

@@ -1,8 +1,7 @@
 """Byzantine-robust aggregation (``repro.core.aggregate`` ROBUST family).
 
 Pins the robust reducers against pure-numpy references, then their
-statistical contracts as property tests (via ``tests/_hypothesis_compat``
-— real hypothesis when installed, a fixed-seed sweep otherwise):
+statistical contracts as property tests (via ``hypothesis``):
 
 - coordinate median / trimmed mean recover the honest mean within the
   honest spread whenever f < C/2 clients upload sign-flipped or
@@ -29,7 +28,7 @@ import pytest
 from repro.core import aggregate
 from repro.core.aggregate import StrategyConfig, make_strategy
 
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 # --------------------------------------------------------- numpy references --

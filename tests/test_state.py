@@ -5,7 +5,7 @@ drivers, so these tests pin its two contracts directly on a real
 sharded round state carrying every optional block (int8_topk codec
 residuals, SCAFFOLD control variates, server-Adam moments):
 
-- **Round-trip identity** (property-style, via ``_hypothesis_compat``):
+- **Round-trip identity** (property-based, via ``hypothesis``):
   for every registered block and any sampled id set, gathering the K
   rows and scattering them back unchanged reproduces the full state
   bit-exactly — the invariant that makes the drivers' shared
@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import state as rstate
 
@@ -67,7 +67,7 @@ def test_registry_covers_real_state():
     assert optional == {"codec", "strat"}
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(c=st.sampled_from([2, 4, 8, 11]), k=st.integers(1, 8),
        seed=st.integers(0, 10**6))
 def test_sample_scatter_roundtrip(c, k, seed):
