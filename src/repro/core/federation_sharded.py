@@ -269,6 +269,12 @@ def make_blendfl_round(spec: ShardedFedSpec):
     gather index under jit (``perm_b`` included), ids must lie in
     [0, n_clients): out-of-range values clamp silently instead of
     raising, so validate on the host when ids come from untrusted input.
+
+    Each stage that runs traces under a ``jax.named_scope`` (``unimodal``,
+    ``vfl``, ``paired``, ``scaffold``, ``forge``, ``codec_uplink``,
+    ``aggregate`` with ``score`` and ``blend`` inside under BlendAvg,
+    ``server_update``, ``codec_downlink``, ``scatter``), so the
+    ``op_name`` of every device op in a profile names its stage.
     """
     fns = make_phase_fns(spec.engine_cfg)
     K = spec.k_round
@@ -297,14 +303,16 @@ def make_blendfl_round(spec: ShardedFedSpec):
         new_global = dict(global_models)
         infos = {}
         for mod, x_val in (("A", val_a), ("B", val_b)):
-            scores = jax.vmap(lambda f, g: uni_score(f, g, x_val))(
-                models[f"f_{mod}"], models[f"g_{mod}"])
-            gscore = uni_score(global_models[f"f_{mod}"],
-                               global_models[f"g_{mod}"], x_val)
+            with jax.named_scope("score"):
+                scores = jax.vmap(lambda f, g: uni_score(f, g, x_val))(
+                    models[f"f_{mod}"], models[f"g_{mod}"])
+                gscore = uni_score(global_models[f"f_{mod}"],
+                                   global_models[f"g_{mod}"], x_val)
             cand = {"f": models[f"f_{mod}"], "g": models[f"g_{mod}"]}
             glob = {"f": global_models[f"f_{mod}"], "g": global_models[f"g_{mod}"]}
-            blended, omega, _ = fns.blendavg_update(glob, cand, scores, gscore,
-                                                    staleness=staleness)
+            with jax.named_scope("blend"):
+                blended, omega, _ = fns.blendavg_update(
+                    glob, cand, scores, gscore, staleness=staleness)
             new_global[f"f_{mod}"], new_global[f"g_{mod}"] = blended["f"], blended["g"]
             infos[f"omega_{mod}"] = omega
 
@@ -313,12 +321,14 @@ def make_blendfl_round(spec: ShardedFedSpec):
         cand = stack_with(models["g_M"], server_gmv)
         stale_m = (None if staleness is None
                    else jnp.concatenate([staleness, jnp.zeros(1, jnp.float32)]))
-        scores = jax.vmap(lambda gm: multi_score(gm, new_global["f_A"],
-                                                 new_global["f_B"]))(cand)
-        gscore = multi_score(global_models["g_M"], new_global["f_A"],
-                             new_global["f_B"])
-        new_global["g_M"], infos["omega_M"], _ = fns.blendavg_update(
-            global_models["g_M"], cand, scores, gscore, staleness=stale_m)
+        with jax.named_scope("score"):
+            scores = jax.vmap(lambda gm: multi_score(
+                gm, new_global["f_A"], new_global["f_B"]))(cand)
+            gscore = multi_score(global_models["g_M"], new_global["f_A"],
+                                 new_global["f_B"])
+        with jax.named_scope("blend"):
+            new_global["g_M"], infos["omega_M"], _ = fns.blendavg_update(
+                global_models["g_M"], cand, scores, gscore, staleness=stale_m)
         return new_global, infos
 
     def aggregate_weighted(models, server_gmv, global_models, batch):
@@ -420,52 +430,59 @@ def make_blendfl_round(spec: ShardedFedSpec):
         # phase 1: local unimodal training. Ragged federations (the
         # FederatedBatcher) ship real 0/1 row masks; the uniform synthetic
         # path omits them and every padded row is live.
-        p1 = {"xa": batch["partial_a"], "ya": batch["partial_ya"],
-              "ma": batch.get("partial_ma",
-                              jnp.ones(batch["partial_ya"].shape[:2], jnp.float32)),
-              "xb": batch["partial_b"], "yb": batch["partial_yb"],
-              "mb": batch.get("partial_mb",
-                              jnp.ones(batch["partial_yb"].shape[:2], jnp.float32))}
-        models, opt_state, i1 = fns.unimodal_step(models, opt_state, p1, strat)
-        # average over clients that actually held rows (all of them in the
-        # uniform layout, where this reduces to the plain mean)
-        wa = (i1["n_a"] > 0).astype(jnp.float32)
-        wb = (i1["n_b"] > 0).astype(jnp.float32)
-        loss_uni = ((jnp.sum(i1["loss_a"] * wa) + jnp.sum(i1["loss_b"] * wb))
-                    / jnp.maximum(jnp.sum(wa) + jnp.sum(wb), 1.0))
+        with jax.named_scope("unimodal"):
+            p1 = {"xa": batch["partial_a"], "ya": batch["partial_ya"],
+                  "ma": batch.get("partial_ma", jnp.ones(
+                      batch["partial_ya"].shape[:2], jnp.float32)),
+                  "xb": batch["partial_b"], "yb": batch["partial_yb"],
+                  "mb": batch.get("partial_mb", jnp.ones(
+                      batch["partial_yb"].shape[:2], jnp.float32))}
+            models, opt_state, i1 = fns.unimodal_step(models, opt_state, p1,
+                                                      strat)
+            # average over clients that actually held rows (all of them in
+            # the uniform layout, where this reduces to the plain mean)
+            wa = (i1["n_a"] > 0).astype(jnp.float32)
+            wb = (i1["n_b"] > 0).astype(jnp.float32)
+            loss_uni = ((jnp.sum(i1["loss_a"] * wa)
+                         + jnp.sum(i1["loss_b"] * wb))
+                        / jnp.maximum(jnp.sum(wa) + jnp.sum(wb), 1.0))
 
         # phase 2: split (VFL) training; identity gather on the a side,
         # the PSI permutation on the b side. ``frag_w`` zero-weights
         # padded/unmatched alignment rows; ``frag_part_*`` excludes
         # clients with no live aligned rows from the param update.
-        p2 = {"xa": batch["frag_a"], "xb": batch["frag_b"],
-              "gather_a": jnp.arange(K * spec.n_frag, dtype=jnp.int32),
-              "gather_b": batch["perm_b"],
-              "y": batch["frag_y"].reshape(K * spec.n_frag, -1),
-              "w": batch.get("frag_w"),
-              "part_a": batch.get("frag_part_a"),
-              "part_b": batch.get("frag_part_b")}
-        models, server_gmv, opt_state, srv_state, loss_vfl = fns.vfl_step(
-            models, server_gmv, opt_state, srv_state, p2, strat)
+        with jax.named_scope("vfl"):
+            p2 = {"xa": batch["frag_a"], "xb": batch["frag_b"],
+                  "gather_a": jnp.arange(K * spec.n_frag, dtype=jnp.int32),
+                  "gather_b": batch["perm_b"],
+                  "y": batch["frag_y"].reshape(K * spec.n_frag, -1),
+                  "w": batch.get("frag_w"),
+                  "part_a": batch.get("frag_part_a"),
+                  "part_b": batch.get("frag_part_b")}
+            models, server_gmv, opt_state, srv_state, loss_vfl = fns.vfl_step(
+                models, server_gmv, opt_state, srv_state, p2, strat)
 
         # phase 3: local multimodal training on paired rows
-        p3 = {"xa": batch["paired_a"], "xb": batch["paired_b"],
-              "y": batch["paired_y"],
-              "m": batch.get("paired_m",
-                             jnp.ones(batch["paired_y"].shape[:2], jnp.float32))}
-        models, opt_state, i3 = fns.paired_step(models, opt_state, p3, strat)
-        wp = (i3["n"] > 0).astype(jnp.float32)
-        loss_paired = (jnp.sum(i3["loss"] * wp)
-                       / jnp.maximum(jnp.sum(wp), 1.0))
+        with jax.named_scope("paired"):
+            p3 = {"xa": batch["paired_a"], "xb": batch["paired_b"],
+                  "y": batch["paired_y"],
+                  "m": batch.get("paired_m", jnp.ones(
+                      batch["paired_y"].shape[:2], jnp.float32))}
+            models, opt_state, i3 = fns.paired_step(models, opt_state, p3,
+                                                    strat)
+            wp = (i3["n"] > 0).astype(jnp.float32)
+            loss_paired = (jnp.sum(i3["loss"] * wp)
+                           / jnp.maximum(jnp.sum(wp), 1.0))
 
         # SCAFFOLD control-variate round update on the TRUE trained
         # weights (Option II runs server-side on what the clients really
         # computed — before the lossy uplink codec touches the
         # candidates), scaled by the participation fraction K/C
         if scfg.control:
-            new_cg, new_cl = fns.scaffold_round(
-                state["strat"]["c_global"], c_local, anchor, models,
-                scaffold_steps, K / spec.n_clients)
+            with jax.named_scope("scaffold"):
+                new_cg, new_cl = fns.scaffold_round(
+                    state["strat"]["c_global"], c_local, anchor, models,
+                    scaffold_steps, K / spec.n_clients)
 
         # gradient-space uplink attackers: each participant ships
         # anchor + coef * (trained - anchor). coef is DATA (the attacker
@@ -484,13 +501,15 @@ def make_blendfl_round(spec: ShardedFedSpec):
                                  - a.astype(jnp.float32))).astype(t.dtype)
                 return jnp.where(c == 1.0, t, forged)
 
-            models = jax.tree.map(forge, models, anchor)
+            with jax.named_scope("forge"):
+                models = jax.tree.map(forge, models, anchor)
 
         # wire codec, uplink leg: the trained weights become candidates
         # only after the lossy client->server round-trip — aggregation
         # scores and blends what the server would actually receive
         if codec_on:
-            models, resid_up = fns.codec_uplink(models, base, resid_up)
+            with jax.named_scope("codec_uplink"):
+                models, resid_up = fns.codec_uplink(models, base, resid_up)
 
         # phase 4: aggregation + broadcast. BlendAvg scores candidates on
         # the replicated val shard (Eq. 9-11); the score-free strategies
@@ -499,71 +518,75 @@ def make_blendfl_round(spec: ShardedFedSpec):
         # resident on every slice). Sampled: participants-only scatter —
         # stragglers keep their stale rows; the trained weights only
         # mattered as candidates, while opt moments ride home per client.
-        if scfg.score_based:
-            new_global, infos = aggregate(
-                models, server_gmv, global_models=state["global_models"],
-                batch=batch, staleness=staleness)
-        else:
-            new_global, infos = aggregate_weighted(
-                models, server_gmv, global_models=state["global_models"],
-                batch=batch)
+        with jax.named_scope("aggregate"):
+            if scfg.score_based:
+                new_global, infos = aggregate(
+                    models, server_gmv, global_models=state["global_models"],
+                    batch=batch, staleness=staleness)
+            else:
+                new_global, infos = aggregate_weighted(
+                    models, server_gmv, global_models=state["global_models"],
+                    batch=batch)
         # server-side optimizer on the blended delta, before anything is
         # broadcast (clients — and the downlink codec — see the adjusted
         # global, and the server's g_M^v re-seeds from it)
         if scfg.server_opt != "none":
-            new_global, srv_moments = fns.server_update(
-                state["strat"]["srv"], new_global, state["global_models"])
+            with jax.named_scope("server_update"):
+                new_global, srv_moments = fns.server_update(
+                    state["strat"]["srv"], new_global, state["global_models"])
         # wire codec, downlink leg: clients adopt the blend as decoded
         # from the broadcast delta. The server's own g_M^v head never
         # crosses a wire — it re-seeds from the TRUE blend below.
         srv_gmv_true = new_global["g_M"]
         if codec_on:
-            new_global, resid_down = fns.codec_downlink(
-                new_global, state["global_models"], state["codec"]["resid_down"])
-        bcast = dict(fns.broadcast(
-            {k: new_global[k] for k in CLIENT_GROUPS}, K))
-        # per-participant sync stamp: K rows in a sampled round (the
-        # registry scatters them to the drawn slots), the whole vector at
-        # full participation (idx None replaces wholesale)
-        last_round = (jnp.full((K,), state["round"], jnp.int32)
-                      if spec.n_sampled
-                      else jnp.full_like(state["last_round"], state["round"]))
+            with jax.named_scope("codec_downlink"):
+                new_global, resid_down = fns.codec_downlink(
+                    new_global, state["global_models"], state["codec"]["resid_down"])
+        with jax.named_scope("scatter"):
+            bcast = dict(fns.broadcast(
+                {k: new_global[k] for k in CLIENT_GROUPS}, K))
+            # per-participant sync stamp: K rows in a sampled round (the
+            # registry scatters them to the drawn slots), the whole vector at
+            # full participation (idx None replaces wholesale)
+            last_round = (jnp.full((K,), state["round"], jnp.int32)
+                          if spec.n_sampled
+                          else jnp.full_like(state["last_round"], state["round"]))
 
-        # participation telemetry for the host-side scheduler: this
-        # round's per-client omega (mean over the three heads' Eq. 10
-        # weights; omega_M's trailing server-head slot excluded) folds
-        # into the EMA at the participants' slots only, mirroring the
-        # async broadcast. Pure jnp — the policy choice is host-side, so
-        # the compiled round is identical across policies. The update
-        # math runs on the gathered rows; WHERE the rows land is the
-        # registry scatter's job.
-        cli_omega = (infos["omega_A"] + infos["omega_B"]
-                     + infos["omega_M"][: K]) / 3.0
-        new_sched = {
-            "omega_ema": schedule.ema_update(sub["sched"]["omega_ema"],
-                                             cli_omega, spec.ema_beta),
-            "part_count": sub["sched"]["part_count"] + 1,
-            "last_round": last_round,
-        }
+            # participation telemetry for the host-side scheduler: this
+            # round's per-client omega (mean over the three heads' Eq. 10
+            # weights; omega_M's trailing server-head slot excluded) folds
+            # into the EMA at the participants' slots only, mirroring the
+            # async broadcast. Pure jnp — the policy choice is host-side, so
+            # the compiled round is identical across policies. The update
+            # math runs on the gathered rows; WHERE the rows land is the
+            # registry scatter's job.
+            cli_omega = (infos["omega_A"] + infos["omega_B"]
+                         + infos["omega_M"][: K]) / 3.0
+            new_sched = {
+                "omega_ema": schedule.ema_update(sub["sched"]["omega_ema"],
+                                                 cli_omega, spec.ema_beta),
+                "part_count": sub["sched"]["part_count"] + 1,
+                "last_round": last_round,
+            }
 
-        # ONE registry-routed scatter writes the round back: stacked
-        # rows land at the sampled slots, global blocks replace.
-        updates = {"models": bcast, "server_gmv": srv_gmv_true,
-                   "global_models": new_global, "opt": opt_state,
-                   "srv_opt": srv_state, "last_round": last_round,
-                   "round": state["round"] + 1, "sched": new_sched}
-        if codec_on:
-            updates["codec"] = {"resid_up": resid_up,
-                                "resid_down": resid_down}
-        if scfg.stateful:
-            new_strat = {}
-            if scfg.control:
-                new_strat["c_global"] = new_cg
-                new_strat["c_local"] = new_cl
-            if scfg.server_opt != "none":
-                new_strat["srv"] = srv_moments
-            updates["strat"] = new_strat
-        state = rstate.scatter(state, updates, idx)
+            # ONE registry-routed scatter writes the round back: stacked
+            # rows land at the sampled slots, global blocks replace.
+            updates = {"models": bcast, "server_gmv": srv_gmv_true,
+                       "global_models": new_global, "opt": opt_state,
+                       "srv_opt": srv_state, "last_round": last_round,
+                       "round": state["round"] + 1, "sched": new_sched}
+            if codec_on:
+                updates["codec"] = {"resid_up": resid_up,
+                                    "resid_down": resid_down}
+            if scfg.stateful:
+                new_strat = {}
+                if scfg.control:
+                    new_strat["c_global"] = new_cg
+                    new_strat["c_local"] = new_cl
+                if scfg.server_opt != "none":
+                    new_strat["srv"] = srv_moments
+                updates["strat"] = new_strat
+            state = rstate.scatter(state, updates, idx)
         metrics = dict(loss_uni=loss_uni, loss_vfl=loss_vfl,
                        loss_paired=loss_paired, **infos)
         return state, metrics

@@ -33,6 +33,12 @@ Design points:
   the dry-run shardings from ``repro.launch.shardings``) while the
   device executes the current round, hiding host batch-build time
   behind device compute.
+- **Measured where the work happens.** ``build``, ``put`` and the
+  prefetch wait open ``FederatedBatcher.build`` / ``.put`` / ``.wait``
+  host spans carrying the round number, seen by any running profiler
+  session; cumulative counters (``build_seconds``, ``read_seconds`` /
+  ``read_bytes`` of the client-array reads inside it, ``put_seconds``,
+  ``stall_seconds``, ``rounds_built``) are plain attributes.
 """
 from __future__ import annotations
 
@@ -94,6 +100,17 @@ CLIENT_KEYS = ("partial_a", "partial_ya", "partial_b", "partial_yb",
                "paired_a", "paired_b", "paired_y")
 
 _SENTINEL = object()  # end-of-stream marker for the prefetch queue
+
+
+def _span(name: str, round_no: int | None):
+    """A host span (``jax.profiler.TraceAnnotation``) carrying the round
+    number as metadata. Inert, about a microsecond, when no profiler
+    session is running."""
+    from jax.profiler import TraceAnnotation
+
+    if round_no is None:
+        return TraceAnnotation(name)
+    return TraceAnnotation(name, round=int(round_no))
 
 
 def _rows(ds: dict, key: str) -> int:
@@ -190,6 +207,11 @@ class FederatedBatcher:
         self.stall_seconds = 0.0  # prefetch mode: consumer time blocked
         # waiting for a staged batch (the build time prefetch FAILED to hide)
         self.rounds_built = 0
+        # inside build: host time and bytes of the client-array reads (a
+        # store's memory-map gathers); the rest of build is slab assembly
+        self.read_seconds = 0.0
+        self.read_bytes = 0
+        self.put_seconds = 0.0  # host time in put (the device transfer)
         # the replicated val set never changes: transfer once, with the
         # configured shardings so the jitted round never re-shards it
         import jax
@@ -271,7 +293,8 @@ class FederatedBatcher:
         return rng.permutation(avail)[:cap]
 
     def build(self, round_no: int, sched: dict | None = None) -> dict:
-        """Build round ``round_no``'s host batch (numpy, unsharded).
+        """Build round ``round_no``'s host batch (numpy, unsharded), in a
+        ``FederatedBatcher.build`` span, counted in ``build_seconds``.
 
         ``sched`` is the round-state telemetry block (numpy ``omega_ema``
         / ``part_count`` / ``last_round``) a state-reading participation
@@ -282,6 +305,23 @@ class FederatedBatcher:
         so bit-exact resume holds for every policy.
         """
         t0 = time.perf_counter()
+        with _span("FederatedBatcher.build", round_no):
+            batch = self._assemble(round_no, sched)
+        self.build_seconds += time.perf_counter() - t0
+        self.rounds_built += 1
+        return batch
+
+    def _read(self, rows, sel) -> np.ndarray:
+        """``rows[sel]`` of one client array, counted in ``read_seconds``
+        and ``read_bytes``."""
+        t0 = time.perf_counter()
+        out = rows[sel]
+        self.read_seconds += time.perf_counter() - t0
+        self.read_bytes += out.nbytes
+        return out
+
+    def _assemble(self, round_no: int, sched: dict | None) -> dict:
+        """The body of ``build``."""
         s = self.spec
         rng = np.random.default_rng([self.seed, int(round_no)])
         K = s.k_round
@@ -337,10 +377,10 @@ class FederatedBatcher:
                 n = len(sel)
                 if n == 0:
                     continue
-                x[k, :n] = ds[xk][sel]
+                x[k, :n] = self._read(ds[xk], sel)
                 if y is not None:
-                    y[k, :n] = (_flip(ds[yk][sel], s.kind) if flip[k]
-                                else ds[yk][sel])
+                    y_rows = self._read(ds[yk], sel)
+                    y[k, :n] = _flip(y_rows, s.kind) if flip[k] else y_rows
                 if bdoor[k]:
                     # targeted backdoor (scenario `backdoor:` events): a
                     # deterministic prefix of the drawn rows gets the
@@ -376,13 +416,16 @@ class FederatedBatcher:
             sel_a = self._draw(rng, _rows(ds, "frag_a"), nf)
             sel_b = self._draw(rng, _rows(ds, "frag_b"), nf)
             if len(sel_a):
-                fa[k, : len(sel_a)] = ds["frag_a"][sel_a]
-                fy[k, : len(sel_a)] = (_flip(ds["frag_y"][sel_a], s.kind)
-                                       if flip[k] else ds["frag_y"][sel_a])
-                ids_a[k * nf : k * nf + len(sel_a)] = ds["frag_ids_a"][sel_a]
+                fa[k, : len(sel_a)] = self._read(ds["frag_a"], sel_a)
+                fy_rows = self._read(ds["frag_y"], sel_a)
+                fy[k, : len(sel_a)] = (_flip(fy_rows, s.kind) if flip[k]
+                                       else fy_rows)
+                ids_a[k * nf : k * nf + len(sel_a)] = self._read(
+                    ds["frag_ids_a"], sel_a)
             if len(sel_b):
-                fb[k, : len(sel_b)] = ds["frag_b"][sel_b]
-                ids_b[k * nf : k * nf + len(sel_b)] = ds["frag_ids_b"][sel_b]
+                fb[k, : len(sel_b)] = self._read(ds["frag_b"], sel_b)
+                ids_b[k * nf : k * nf + len(sel_b)] = self._read(
+                    ds["frag_ids_b"], sel_b)
         bpos = np.flatnonzero(ids_b >= 0)
         order = np.argsort(ids_b[bpos], kind="stable")
         sorted_b = ids_b[bpos][order]
@@ -416,20 +459,22 @@ class FederatedBatcher:
             batch["attack_coef"] = (
                 self.scenario.attack_coef(int(round_no), idx)
                 if self.scenario is not None else np.ones(len(idx), _F32))
-        self.build_seconds += time.perf_counter() - t0
-        self.rounds_built += 1
         return batch
 
-    def put(self, host_batch: dict) -> dict:
+    def put(self, host_batch: dict, round_no: int | None = None) -> dict:
         """Transfer one host batch to device with the configured
-        shardings; the cached val set rides along untouched."""
+        shardings; the cached val set rides along untouched. A
+        ``FederatedBatcher.put`` span, counted in ``put_seconds``."""
         import jax
 
-        if self.shardings is not None:
-            moved = {k: jax.device_put(v, self.shardings[k])
-                     for k, v in host_batch.items()}
-        else:
-            moved = jax.device_put(host_batch)
+        t0 = time.perf_counter()
+        with _span("FederatedBatcher.put", round_no):
+            if self.shardings is not None:
+                moved = {k: jax.device_put(v, self.shardings[k])
+                         for k, v in host_batch.items()}
+            else:
+                moved = jax.device_put(host_batch)
+        self.put_seconds += time.perf_counter() - t0
         return dict(moved, **self._val)
 
     # ---- double-buffered round stream ----
@@ -447,7 +492,8 @@ class FederatedBatcher:
         thread contends with the XLA CPU compute pool, and the copy is
         cheap next to the build. ``stall_seconds`` accumulates consumer
         time spent waiting for a staged batch — the build time prefetch
-        failed to hide.
+        failed to hide — and a ``FederatedBatcher.wait`` span covers
+        each such wait.
 
         ``telemetry_fn() -> dict`` supplies the current round-state sched
         telemetry for a state-reading participation policy (staleness /
@@ -467,12 +513,12 @@ class FederatedBatcher:
                     f"policy {self.policy.name!r} needs per-round state "
                     "telemetry; pass telemetry_fn to rounds()")
             for r in range(start, stop):
-                yield r, self.put(self.build(r, telemetry_fn()))
+                yield r, self.put(self.build(r, telemetry_fn()), r)
             return
         depth = self.prefetch if prefetch is None else int(prefetch)
         if depth <= 0:
             for r in range(start, stop):
-                yield r, self.put(self.build(r))
+                yield r, self.put(self.build(r), r)
             return
 
         q: queue.Queue = queue.Queue(maxsize=depth)
@@ -500,15 +546,18 @@ class FederatedBatcher:
                              name="federated-batcher-prefetch")
         t.start()
         try:
+            r = start
             while True:
-                t0 = time.perf_counter()
-                item = q.get()
-                self.stall_seconds += time.perf_counter() - t0
+                with _span("FederatedBatcher.wait", r):
+                    t0 = time.perf_counter()
+                    item = q.get()
+                    self.stall_seconds += time.perf_counter() - t0
                 if item is _SENTINEL:
                     return
                 if isinstance(item, BaseException):
                     raise item
                 r, host_batch = item
-                yield r, self.put(host_batch)
+                yield r, self.put(host_batch, r)
+                r += 1
         finally:
             stop_evt.set()

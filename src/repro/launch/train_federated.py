@@ -42,6 +42,7 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import (latest_step, read_manifest, restore_checkpoint,
@@ -237,26 +238,38 @@ def run(args, spec, batcher, round_fn, start: int, state: dict,
         # round below, so this always reads the latest round's telemetry
         return telemetry_from_state(state)
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     for r, batch in batcher.rounds(start, args.rounds,
                                    telemetry_fn=sched_telemetry):
-        state, metrics = round_fn(state, batch)
-        row = {k: float(np.asarray(v)) for k, v in metrics.items()
-               if np.asarray(v).ndim == 0}
-        row["round"] = r
+        state, row = _step(round_fn, state, batch, r)
         history.append(row)
         if args.log_every and (r + 1) % args.log_every == 0:
             log(f"round {r + 1:4d} loss_uni {row['loss_uni']:.4f} "
                 f"loss_vfl {row['loss_vfl']:.4f} "
                 f"loss_paired {row['loss_paired']:.4f} "
-                f"({(time.time() - t0) / (r + 1 - start):.2f}s/round)")
+                f"({time.perf_counter() - t0:.2f}s this round)")
         if args.ckpt_dir and args.ckpt_every and (r + 1) % args.ckpt_every == 0:
             meta = {"round": r + 1, "loss_uni": row["loss_uni"]}
             if fp is not None:
                 meta["store_fingerprint"] = fp
             out = save_checkpoint(args.ckpt_dir, r + 1, state, meta)
             log(f"checkpointed round {r + 1} -> {out}")
+        t0 = time.perf_counter()
     return history
+
+
+def _step(round_fn, state: dict, batch: dict, r: int) -> tuple[dict, dict]:
+    """One round: dispatch ``round_fn`` in a ``train_federated.dispatch``
+    span, then read its metrics back to the host in a
+    ``train_federated.sync`` span. Returns (state', the round's scalar
+    metrics as floats, with ``round``)."""
+    with TraceAnnotation("train_federated.dispatch", round=r):
+        state, metrics = round_fn(state, batch)
+    with TraceAnnotation("train_federated.sync", round=r):
+        host = {k: np.asarray(v) for k, v in metrics.items()}
+    row = {k: float(v) for k, v in host.items() if v.ndim == 0}
+    row["round"] = r
+    return state, row
 
 
 def _fingerprint(batcher) -> str | None:
@@ -281,8 +294,8 @@ def run_scenario(args, spec, batcher, round_fn, mesh, start: int, state: dict,
     round_fns = {spec.n_clients: round_fn}
     history = []
     fp = _fingerprint(batcher)
-    t0 = time.time()
     for r in range(start, args.rounds):
+        t0 = time.perf_counter()
         ev = scenario.events_at(r)
         n_now = scenario.n_clients_at(r, batcher.n_initial)
         cap = rstate.capacity_for(n_now)
@@ -310,18 +323,15 @@ def run_scenario(args, spec, batcher, round_fn, mesh, start: int, state: dict,
         sched = (telemetry_from_state(state)
                  if batcher.policy is not None and batcher.policy.needs_state
                  else None)
-        batch = batcher.put(batcher.build(r, sched))
-        state, metrics = round_fns[spec.n_clients](state, batch)
-        row = {k: float(np.asarray(v)) for k, v in metrics.items()
-               if np.asarray(v).ndim == 0}
-        row["round"] = r
+        batch = batcher.put(batcher.build(r, sched), r)
+        state, row = _step(round_fns[spec.n_clients], state, batch, r)
         history.append(row)
         if args.log_every and (r + 1) % args.log_every == 0:
             log(f"round {r + 1:4d} loss_uni {row['loss_uni']:.4f} "
                 f"loss_vfl {row['loss_vfl']:.4f} "
                 f"loss_paired {row['loss_paired']:.4f} "
                 f"[{n_now} clients / cap {spec.n_clients}] "
-                f"({(time.time() - t0) / (r + 1 - start):.2f}s/round)")
+                f"({time.perf_counter() - t0:.2f}s this round)")
         if args.ckpt_dir and args.ckpt_every and (r + 1) % args.ckpt_every == 0:
             meta = {"round": r + 1, "loss_uni": row["loss_uni"]}
             if fp is not None:
@@ -596,7 +606,10 @@ def main() -> None:
     else:
         run(args, spec, batcher, round_fn, start, state)
     print(f"done ({args.rounds - start} rounds; host batch-build "
-          f"{batcher.build_seconds:.2f}s over {batcher.rounds_built} builds).")
+          f"{batcher.build_seconds:.2f}s over {batcher.rounds_built} builds, "
+          f"of which reads {batcher.read_seconds:.2f}s for "
+          f"{batcher.read_bytes / 1e9:.3f} GB; device put "
+          f"{batcher.put_seconds:.2f}s).")
 
 
 if __name__ == "__main__":
