@@ -33,12 +33,21 @@ Design points:
   the dry-run shardings from ``repro.launch.shardings``) while the
   device executes the current round, hiding host batch-build time
   behind device compute.
+- **Each drawn row copied once.** ``build`` gathers a client's drawn
+  rows straight into their block of the ``(K, N, ...)`` slab
+  (``take(..., out=, mode="clip")``; a store's shard maps stay open), and
+  ``rounds()`` recycles its ``prefetch + 2`` slab sets from round to
+  round, so steady-state rounds touch no fresh pages. The private host
+  memory a stream holds is O((prefetch + 2) * K * N * row_bytes);
+  ``build()`` called directly always returns fresh arrays.
 - **Measured where the work happens.** ``build``, ``put`` and the
   prefetch wait open ``FederatedBatcher.build`` / ``.put`` / ``.wait``
   host spans carrying the round number, seen by any running profiler
   session; cumulative counters (``build_seconds``, ``read_seconds`` /
   ``read_bytes`` of the client-array reads inside it, ``put_seconds``,
-  ``stall_seconds``, ``rounds_built``) are plain attributes.
+  ``stall_seconds``, ``rounds_built``, ``slab_allocs`` /
+  ``slab_reuses``, and the store's ``map_opens`` / ``reads_unmapped``)
+  are plain attributes.
 """
 from __future__ import annotations
 
@@ -101,6 +110,10 @@ CLIENT_KEYS = ("partial_a", "partial_ya", "partial_b", "partial_yb",
 
 _SENTINEL = object()  # end-of-stream marker for the prefetch queue
 
+# the feature slabs of a round batch: what rounds() recycles
+_SLAB_KEYS = ("partial_a", "partial_b", "paired_a", "paired_b", "frag_a",
+              "frag_b")
+
 
 def _span(name: str, round_no: int | None):
     """A host span (``jax.profiler.TraceAnnotation``) carrying the round
@@ -116,6 +129,28 @@ def _span(name: str, round_no: int | None):
 def _rows(ds: dict, key: str) -> int:
     v = ds.get(key)
     return 0 if v is None else len(v)
+
+
+def _aliases(arr, host: np.ndarray) -> bool:
+    """Whether a device array's buffers lie in ``host``'s memory: the CPU
+    backend may put a numpy array zero-copy, whatever ``may_alias`` says."""
+    lo = host.ctypes.data
+    hi = lo + host.nbytes
+    return any(sh.device.platform == "cpu"
+               and lo <= sh.data.unsafe_buffer_pointer() < hi
+               for sh in arr.addressable_shards)
+
+
+def _recycle(pool, host_batch: dict, dev_batch: dict) -> None:
+    """Hand a finished round's slab set back to ``pool`` once the device
+    arrays put from it are ready, unless one of them aliases its slab (the
+    set then belongs to the caller's device batch)."""
+    slabs = {k: host_batch[k] for k in _SLAB_KEYS}
+    for k, slab in slabs.items():
+        arr = dev_batch[k].block_until_ready()
+        if _aliases(arr, slab):
+            return
+    pool.put(slabs)
 
 
 def _flip(y: np.ndarray, kind: str) -> np.ndarray:
@@ -212,6 +247,10 @@ class FederatedBatcher:
         self.read_seconds = 0.0
         self.read_bytes = 0
         self.put_seconds = 0.0  # host time in put (the device transfer)
+        # slab sets build allocated fresh / filled again from rounds()' pool
+        self.slab_allocs = 0
+        self.slab_reuses = 0
+        self._lent = threading.local()  # .slabs: the set rounds() lends build
         # the replicated val set never changes: transfer once, with the
         # configured shardings so the jitted round never re-shards it
         import jax
@@ -248,6 +287,16 @@ class FederatedBatcher:
                                        "frag_b", "paired_a"))
              for c in self.clients], np.float64)
 
+    @property
+    def map_opens(self) -> int:
+        """Shard maps the store opened and holds (0 without a store)."""
+        return 0 if self.store is None else self.store.maps.map_opens
+
+    @property
+    def reads_unmapped(self) -> int:
+        """Store reads past the descriptor budget (open, gather, close)."""
+        return 0 if self.store is None else self.store.maps.reads_unmapped
+
     def set_spec(self, spec) -> None:
         """Re-bind after the driver grew the state capacity (a scenario
         join crossed a bucket): same roster, new ``spec.n_clients``."""
@@ -258,13 +307,13 @@ class FederatedBatcher:
                    shardings=None, prefetch: int = 1) -> "FederatedBatcher":
         """Out-of-core loader over a ``repro.data.store.ClientStore``.
 
-        Client arrays stay on disk: ``build()``'s ``ds[key][sel]`` reads
-        open each shard's memory map, gather only the drawn rows, and
-        unmap — peak host RAM per round is O(K*N*row_bytes), independent
-        of the total dataset size. Row counts, dtype/shape validation,
-        and ``_draw`` sizing come from the store manifest (no file IO),
-        and the batch stream is bit-identical to an in-memory
-        ``FederatedBatcher`` over the same arrays for the same
+        Client arrays stay on disk: ``build()`` gathers only the drawn
+        rows through each shard's memory map, which the store holds open
+        (shared, reclaimable page cache); the private host memory is the
+        slabs, independent of the total dataset size. Row counts,
+        dtype/shape validation, and ``_draw`` sizing come from the store
+        manifest (no file IO), and the batch stream is bit-identical to an
+        in-memory ``FederatedBatcher`` over the same arrays for the same
         ``(seed, round)``. ``val=None`` reads the server validation set
         the store's ``import`` recorded.
         """
@@ -320,9 +369,33 @@ class FederatedBatcher:
         self.read_bytes += out.nbytes
         return out
 
+    def _read_into(self, rows, sel, out: np.ndarray) -> None:
+        """``rows[sel]`` gathered straight into ``out`` (a client's block
+        of a slab), counted like ``_read``. ``mode="clip"`` keeps numpy
+        from buffering the output; ``sel`` is in range by construction."""
+        t0 = time.perf_counter()
+        rows.take(sel, axis=0, out=out, mode="clip")
+        self.read_seconds += time.perf_counter() - t0
+        self.read_bytes += (len(sel) * int(np.prod(rows.shape[1:]))
+                            * rows.dtype.itemsize)
+
     def _assemble(self, round_no: int, sched: dict | None) -> dict:
-        """The body of ``build``."""
+        """The body of ``build``. Fills the slab set ``rounds()`` lent this
+        thread, else fresh ones; every element is written (drawn rows, then
+        zeros to the end of each client's block), so a recycled set
+        carries nothing over from the round it last held."""
         s = self.spec
+        lent = getattr(self._lent, "slabs", None)
+        if lent is None:
+            self.slab_allocs += 1
+        else:
+            self.slab_reuses += 1
+
+        def slab(key: str, rows_seq_feat: tuple) -> np.ndarray:
+            if lent is None:
+                return np.empty((s.k_round,) + rows_seq_feat, _F32)
+            return lent[key]
+
         rng = np.random.default_rng([self.seed, int(round_no)])
         K = s.k_round
         if s.n_sampled:
@@ -364,7 +437,7 @@ class FederatedBatcher:
         ]
         paired_sel = [None] * K  # paired rows must align across modalities
         for xk, yk, mk, cap, seq, feat in slabs:
-            x = np.zeros((K, cap, seq, feat), _F32)
+            x = slab(xk, (cap, seq, feat))
             y = np.zeros((K, cap, s.out_dim), _F32) if yk else None
             m = np.zeros((K, cap), _F32) if mk else None
             for k, ds in enumerate(sub):
@@ -375,9 +448,10 @@ class FederatedBatcher:
                     if xk == "paired_a":
                         paired_sel[k] = sel
                 n = len(sel)
+                x[k, n:] = 0.0  # pad rows (the whole block when none drawn)
                 if n == 0:
                     continue
-                x[k, :n] = self._read(ds[xk], sel)
+                self._read_into(ds[xk], sel, x[k, :n])
                 if y is not None:
                     y_rows = self._read(ds[yk], sel)
                     y[k, :n] = _flip(y_rows, s.kind) if flip[k] else y_rows
@@ -407,23 +481,25 @@ class FederatedBatcher:
         # perm_b[i]; rows that are padding or whose partner modality was
         # not drawn this round carry weight 0 (static shape, live mask).
         nf = s.n_frag
-        fa = np.zeros((K, nf, s.seq_a, s.feat_a), _F32)
-        fb = np.zeros((K, nf, s.seq_b, s.feat_b), _F32)
+        fa = slab("frag_a", (nf, s.seq_a, s.feat_a))
+        fb = slab("frag_b", (nf, s.seq_b, s.feat_b))
         fy = np.zeros((K, nf, s.out_dim), _F32)
         ids_a = np.full(K * nf, -1, np.int64)
         ids_b = np.full(K * nf, -2, np.int64)  # never matches ids_a padding
         for k, ds in enumerate(sub):
             sel_a = self._draw(rng, _rows(ds, "frag_a"), nf)
             sel_b = self._draw(rng, _rows(ds, "frag_b"), nf)
+            fa[k, len(sel_a):] = 0.0
+            fb[k, len(sel_b):] = 0.0
             if len(sel_a):
-                fa[k, : len(sel_a)] = self._read(ds["frag_a"], sel_a)
+                self._read_into(ds["frag_a"], sel_a, fa[k, : len(sel_a)])
                 fy_rows = self._read(ds["frag_y"], sel_a)
                 fy[k, : len(sel_a)] = (_flip(fy_rows, s.kind) if flip[k]
                                        else fy_rows)
                 ids_a[k * nf : k * nf + len(sel_a)] = self._read(
                     ds["frag_ids_a"], sel_a)
             if len(sel_b):
-                fb[k, : len(sel_b)] = self._read(ds["frag_b"], sel_b)
+                self._read_into(ds["frag_b"], sel_b, fb[k, : len(sel_b)])
                 ids_b[k * nf : k * nf + len(sel_b)] = self._read(
                     ds["frag_ids_b"], sel_b)
         bpos = np.flatnonzero(ids_b >= 0)
@@ -501,24 +577,45 @@ class FederatedBatcher:
         a true data dependency — so those policies run the synchronous
         path regardless of ``prefetch``: each batch builds only after the
         caller's previous round updated the state the telemetry reads.
-        State-free policies keep the full prefetch overlap."""
+        State-free policies keep the full prefetch overlap.
+
+        Every path recycles the host slabs: when the caller resumes the
+        stream after round r, round r's slab set goes back to a pool once
+        the device arrays put from it are ready, and a later ``build``
+        (still through ``self.build``) fills it again. A set whose device
+        arrays alias it (the CPU backend may put numpy zero-copy) stays
+        with the caller's batch; an empty pool means a fresh set."""
         if self.scenario is not None:
             raise ValueError(
                 "rounds() cannot stream a churn scenario: capacity (and "
                 "with it this loader's spec) may change between rounds — "
                 "drive build()/put() round-by-round from the scenario loop")
-        if (self.policy is not None and self.policy.needs_state):
-            if telemetry_fn is None:
-                raise ValueError(
-                    f"policy {self.policy.name!r} needs per-round state "
-                    "telemetry; pass telemetry_fn to rounds()")
-            for r in range(start, stop):
-                yield r, self.put(self.build(r, telemetry_fn()), r)
-            return
+        needs_state = self.policy is not None and self.policy.needs_state
+        if needs_state and telemetry_fn is None:
+            raise ValueError(
+                f"policy {self.policy.name!r} needs per-round state "
+                "telemetry; pass telemetry_fn to rounds()")
         depth = self.prefetch if prefetch is None else int(prefetch)
-        if depth <= 0:
+        pool: queue.SimpleQueue = queue.SimpleQueue()  # free slab sets
+
+        def build(r: int) -> dict:
+            try:
+                self._lent.slabs = pool.get_nowait()
+            except queue.Empty:
+                pass  # none free yet: build allocates a fresh set
+            try:
+                if needs_state:
+                    return self.build(r, telemetry_fn())
+                return self.build(r)
+            finally:
+                self._lent.slabs = None
+
+        if needs_state or depth <= 0:
             for r in range(start, stop):
-                yield r, self.put(self.build(r), r)
+                host_batch = build(r)
+                dev = self.put(host_batch, r)
+                yield r, dev
+                _recycle(pool, host_batch, dev)
             return
 
         q: queue.Queue = queue.Queue(maxsize=depth)
@@ -536,7 +633,7 @@ class FederatedBatcher:
         def worker():
             try:
                 for r in range(start, stop):
-                    if stop_evt.is_set() or not _feed((r, self.build(r))):
+                    if stop_evt.is_set() or not _feed((r, build(r))):
                         return
                 _feed(_SENTINEL)
             except BaseException as e:  # surface build errors to the
@@ -557,7 +654,9 @@ class FederatedBatcher:
                 if isinstance(item, BaseException):
                     raise item
                 r, host_batch = item
-                yield r, self.put(host_batch, r)
+                dev = self.put(host_batch, r)
+                yield r, dev
+                _recycle(pool, host_batch, dev)
                 r += 1
         finally:
             stop_evt.set()

@@ -6,9 +6,13 @@ the drawn row subsets of each client's arrays — ``build()`` reads
 static row capacity. ``ClientStore`` exploits that access pattern to
 take C past what one host's memory holds: each client's ragged
 dict-of-arrays dataset is written once to per-client ``.npy`` shard
-files, and reads open a memory map, gather exactly the selected rows
-into a fresh array, and unmap — so a training round's peak host RSS is
-O(K * N * row_bytes) regardless of the total dataset size.
+files. Each shard's memory map opens on its first read and stays open for
+the store's life (within the process's file-descriptor budget), and a
+read gathers exactly the selected rows, into a fresh array or straight
+into the caller's slab. The mapped shard pages are shared, reclaimable
+page cache; the private memory a training round holds is the batcher's
+O((prefetch + 2) * K * N * row_bytes) of slabs, regardless of the total
+dataset size.
 
 Layout (one directory per federation)::
 
@@ -28,6 +32,13 @@ Design points:
   a shard file. A missing key means that client holds no such modality
   (zero-row arrays are recorded in the manifest but read back as
   materialized ``np.zeros`` — a zero-length file cannot be mmapped).
+- **Maps held open, within a budget.** Each map holds one file
+  descriptor (``mmap`` dups it). The first read sizes a budget from
+  ``RLIMIT_NOFILE`` and the descriptors already open, raising the soft
+  limit toward the hard one only as far as the store's shards need. Past
+  the budget a read opens, gathers and closes its map; no map is ever
+  evicted, since a cyclic scan over more shards than a cache holds would
+  hit nothing. ``close()`` (or dropping the store) releases the maps.
 - **Writes are atomic.** Shards and manifest are staged in
   ``<store_dir>.tmp`` and ``os.rename``d into place, mirroring the
   checkpoint store's crash-safety contract: a partial import can never
@@ -48,7 +59,9 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import resource
 import shutil
+import threading
 
 import numpy as np
 
@@ -62,35 +75,140 @@ def _client_dirname(cid: int) -> str:
     return f"client_{cid:05d}"
 
 
+# descriptors left free for everything else the process opens (sockets,
+# checkpoint and trace files, the runtime's own)
+_FD_RESERVE = 128
+
+
+def _map_budget(need: int) -> int:
+    """How many more shard maps (one descriptor each) this process may
+    hold open, ``need`` at most. Raises the soft ``RLIMIT_NOFILE`` toward
+    the hard limit as far as ``need`` asks."""
+    try:
+        in_use = len(os.listdir("/proc/self/fd"))
+    except OSError:
+        return 0  # no way to count what is open: hold no maps
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    want = in_use + need + _FD_RESERVE
+    if soft != resource.RLIM_INFINITY and soft < want:
+        raised = want if hard == resource.RLIM_INFINITY else min(want, hard)
+        try:
+            resource.setrlimit(resource.RLIMIT_NOFILE, (raised, hard))
+            soft = raised
+        except (ValueError, OSError):
+            pass
+    if soft == resource.RLIM_INFINITY:
+        return need
+    return max(0, min(need, soft - in_use - _FD_RESERVE))
+
+
+def _close_map(mm: np.memmap) -> None:
+    owner = getattr(mm, "_mmap", None)
+    del mm
+    if owner is not None:
+        try:
+            owner.close()
+        except BufferError:
+            pass  # a read still holds the rows: unmapped when it lets go
+
+
+class ShardMaps:
+    """The shard memory maps of one store, held open for its life.
+
+    A shard's map opens on its first read while the process holds fewer
+    than ``_map_budget`` of this store's maps; past the budget each read
+    opens, gathers and closes (``reads_unmapped``). Cumulative counters:
+    ``map_opens`` (maps kept open: flat once every shard was read) and
+    ``reads_unmapped``."""
+
+    def __init__(self, n_shards: int):
+        self.n_shards = int(n_shards)
+        self.budget: int | None = None  # sized at the first read
+        self._held: dict[str, np.memmap] = {}
+        self._lock = threading.Lock()
+        self.map_opens = 0
+        self.reads_unmapped = 0
+
+    def gather(self, path: str, fn):
+        """``fn(rows)`` over the shard at ``path``, through its held map,
+        or through one opened for this call alone past the budget."""
+        mm = self._held.get(path)
+        if mm is None:
+            with self._lock:
+                mm = self._held.get(path)
+                if mm is None:
+                    if self.budget is None:
+                        self.budget = _map_budget(self.n_shards)
+                    if len(self._held) < self.budget:
+                        mm = np.lib.format.open_memmap(path, mode="r")
+                        self._held[path] = mm
+                        self.map_opens += 1
+                    else:
+                        self.reads_unmapped += 1
+        if mm is not None:
+            return fn(mm)
+        return _gather_unmapped(path, fn)
+
+    def close(self) -> None:
+        """Unmap every held shard (their descriptors close with them)."""
+        with self._lock:
+            held, self._held = self._held, {}
+        while held:
+            _close_map(held.popitem()[1])
+
+
+def _gather_unmapped(path: str, fn):
+    mm = np.lib.format.open_memmap(path, mode="r")
+    try:
+        return fn(mm)
+    finally:
+        _close_map(mm)
+
+
 class ShardRows:
     """Lazy row-reader for one (client, key) shard file.
 
-    Supports exactly the accesses ``FederatedBatcher.build`` performs on
-    an in-memory array — ``len(v)`` and ``v[sel]`` — plus ``.shape`` and
-    ``.dtype`` from the manifest. ``__getitem__`` opens the ``.npy``
-    memory map, materializes the selected rows, and closes the map, so
-    no file pages stay resident between reads.
+    Supports the accesses ``FederatedBatcher.build`` performs on an
+    in-memory array — ``len(v)``, ``v[sel]`` and
+    ``v.take(sel, axis=0, out=..., mode="clip")`` — plus ``.shape`` and
+    ``.dtype`` from the manifest. Reads go through the store's
+    :class:`ShardMaps` (``maps``), so a shard is mapped once for the
+    store's life; without one (the val set) each read maps and unmaps.
+    ``v[sel]`` returns a fresh array; ``take`` gathers the rows straight
+    into ``out`` in one unbuffered copy.
     """
 
-    def __init__(self, path: str, shape: tuple, dtype: np.dtype):
+    def __init__(self, path: str, shape: tuple, dtype: np.dtype,
+                 maps: ShardMaps | None = None):
         self.path = path
         self.shape = tuple(shape)
         self.dtype = np.dtype(dtype)
+        self._maps = maps
 
     def __len__(self) -> int:
         return self.shape[0]
 
+    def _gather(self, fn):
+        if self._maps is None:
+            return _gather_unmapped(self.path, fn)
+        return self._maps.gather(self.path, fn)
+
     def __getitem__(self, sel) -> np.ndarray:
         if self.shape[0] == 0:
             return np.zeros(self.shape, self.dtype)[sel]
-        mm = np.lib.format.open_memmap(self.path, mode="r")
-        try:
-            return np.array(mm[sel])  # gather + copy off the map
-        finally:
-            owner = getattr(mm, "_mmap", None)
-            del mm
-            if owner is not None:
-                owner.close()
+        return self._gather(lambda mm: np.array(mm[sel]))  # copy off the map
+
+    def take(self, indices, axis: int, *, out: np.ndarray,
+             mode: str = "clip") -> np.ndarray:
+        """``numpy.take`` of rows into ``out``. ``mode="clip"`` keeps numpy
+        from buffering the whole output, so the rows move once, map to
+        ``out``."""
+        if axis != 0:
+            raise ValueError("ShardRows.take gathers rows (axis 0) only")
+        if len(indices):
+            self._gather(lambda mm: np.take(mm, indices, axis=0, out=out,
+                                            mode=mode))
+        return out
 
     def read(self) -> np.ndarray:
         """Materialize the whole shard (val set, tests)."""
@@ -156,6 +274,14 @@ class ClientStore:
             raise ValueError(
                 f"store version {self.manifest.get('version')!r} != "
                 f"{STORE_VERSION} (incompatible layout)")
+        self.maps = ShardMaps(sum(
+            int(ent["shape"][0]) > 0 for c in self.manifest["clients"]
+            for ent in c["keys"].values()))
+
+    def close(self) -> None:
+        """Release the shard maps now, not when the store is dropped; a
+        later read maps its shard again."""
+        self.maps.close()
 
     # ---- manifest accessors (no file IO) ----
 
@@ -186,7 +312,8 @@ class ClientStore:
     def shard(self, cid: int, key: str) -> ShardRows:
         ent = self.manifest["clients"][cid]["keys"][key]
         path = os.path.join(self.store_dir, _client_dirname(cid), key + ".npy")
-        return ShardRows(path, tuple(ent["shape"]), np.dtype(ent["dtype"]))
+        return ShardRows(path, tuple(ent["shape"]), np.dtype(ent["dtype"]),
+                         self.maps)
 
     def client(self, cid: int) -> ClientView:
         return ClientView(self, cid)

@@ -608,7 +608,10 @@ def main() -> None:
     print(f"done ({args.rounds - start} rounds; host batch-build "
           f"{batcher.build_seconds:.2f}s over {batcher.rounds_built} builds, "
           f"of which reads {batcher.read_seconds:.2f}s for "
-          f"{batcher.read_bytes / 1e9:.3f} GB; device put "
+          f"{batcher.read_bytes / 1e9:.3f} GB, {batcher.map_opens} shard "
+          f"maps opened, {batcher.reads_unmapped} reads unmapped; slab "
+          f"sets reused {batcher.slab_reuses} of "
+          f"{batcher.slab_reuses + batcher.slab_allocs} builds; device put "
           f"{batcher.put_seconds:.2f}s).")
 
 

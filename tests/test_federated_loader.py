@@ -3,10 +3,10 @@
 Covers the ragged-client data subsystem (``FederatedBatcher``): stateless
 per-round determinism, static shapes with real 0/1 masks, id-based VFL
 alignment, zero-row-modality exclusion semantics (the engine's
-``_where_clients`` contract), prefetch equivalence — and the full
-round-state save/restore path: a federation checkpointed mid-run and
-resumed must produce bit-identical round metrics to an uninterrupted run
-(full participation and K-of-C sampled/async)."""
+``_where_clients`` contract), prefetch equivalence, recycled slabs —
+and the full round-state save/restore path: a federation checkpointed
+mid-run and resumed must produce bit-identical round metrics to an
+uninterrupted run (full participation and K-of-C sampled/async)."""
 import argparse
 
 import jax
@@ -131,6 +131,103 @@ def test_prefetch_stream_matches_sync_stream(loader):
         for k in sync[r]:
             np.testing.assert_array_equal(np.asarray(sync[r][k]),
                                           np.asarray(pref[r][k]), err_msg=k)
+
+
+# ------------------------------------------------------ recycled slabs --
+
+def _shrinking_batchers(tmp_path, source: str, policy: str = "uniform"):
+    """Two batchers over one ragged federation (6 clients, 3 sampled a
+    round, so a slot's drawn count changes from round to round; one
+    client holds no b-side partial rows, another no fragmented or paired
+    rows): (spec, under test, reference)."""
+    spec = _spec(n_clients=6, n_sampled=3, policy=policy)
+    rng = np.random.default_rng(21)
+    clients = _ragged_clients(spec, rng, zero_b_client=2,
+                              n_rows={4: {"fr": 0, "pr": 0}})
+    val = _val(spec, rng)
+    if source == "store":
+        from repro.data.store import write_store
+
+        store = write_store(str(tmp_path / "store"), clients, val)
+        return spec, *(FederatedBatcher.from_store(store, spec, seed=9)
+                       for _ in range(2))
+    return spec, *(FederatedBatcher(clients, spec, val, seed=9)
+                   for _ in range(2))
+
+
+def _sched(n: int, r: int) -> dict:
+    """Telemetry before round ``r``: the stalest clients rotate."""
+    return {"last_round": np.roll(np.arange(n, dtype=np.int64) - n, r),
+            "omega_ema": np.linspace(0.0, 1.0, n),
+            "part_count": np.zeros(n, np.int64)}
+
+
+def _assert_batch_equal(got: dict, want: dict, r: int) -> None:
+    assert set(got) == set(want)
+    for k in want:
+        g, w = np.asarray(got[k]), np.asarray(want[k])
+        assert g.dtype == w.dtype, k
+        np.testing.assert_array_equal(g, w, err_msg=f"round {r} key {k}")
+
+
+@pytest.mark.parametrize("source", ["memory", "store"])
+@pytest.mark.parametrize("prefetch,policy", [
+    (0, "uniform"), (1, "uniform"), (2, "uniform"), (1, "staleness")])
+def test_recycled_slab_stream_is_bit_identical(tmp_path, monkeypatch,
+                                               source, prefetch, policy):
+    """Over ``prefetch + 6`` rounds the stream of ``rounds()``, which
+    refills recycled slabs, equals ``put(build(r))`` over fresh arrays bit
+    for bit, pad zeros included, on every path: prefetched, depth 0, and
+    the synchronous path of a state-reading policy."""
+    from repro.data import pipeline
+
+    # the CPU backend may put numpy zero-copy, which keeps a set out of
+    # the pool; without that check every set recycles, as on a chip, and
+    # each batch is read before the stream resumes
+    monkeypatch.setattr(pipeline, "_aliases", lambda arr, host: False)
+    spec, b, ref = _shrinking_batchers(tmp_path, source, policy)
+    n = prefetch + 6
+    scheds = [_sched(spec.n_clients, r) if policy != "uniform" else None
+              for r in range(n)]
+    told = iter(scheds)  # the synchronous path asks once per round, in order
+    live = []
+    for r, dev in b.rounds(0, n, prefetch=prefetch,
+                           telemetry_fn=lambda: next(told)):
+        _assert_batch_equal(dev, ref.put(ref.build(r, scheds[r]), r), r)
+        live.append(np.stack([np.asarray(dev[mk]).sum(1) for mk in
+                              ("partial_ma", "partial_mb", "paired_m")]))
+    assert b.slab_allocs + b.slab_reuses == n == b.rounds_built
+    assert b.slab_allocs <= prefetch + 2 and b.slab_reuses > 0
+    assert ref.slab_reuses == 0  # build() alone always allocates
+    # a slot drew fewer rows than in the round before: its pad rows had
+    # to be zeroed again
+    assert any((live[r + 1] < live[r]).any() for r in range(n - 1))
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_kept_device_batches_are_never_rewritten(tmp_path, prefetch):
+    """Every device batch ``rounds()`` yielded, kept to the end of the
+    stream, still equals a fresh build of its round: recycling never
+    writes into memory a kept batch lives in."""
+    _, b, ref = _shrinking_batchers(tmp_path, "memory")
+    kept = dict(b.rounds(0, prefetch + 6, prefetch=prefetch))
+    for r, dev in kept.items():
+        _assert_batch_equal(dev, ref.put(ref.build(r), r), r)
+
+
+def test_alias_check_sees_zero_copy_puts():
+    """``_aliases`` is true exactly when a write to the host array shows
+    in the device array."""
+    from repro.data.pipeline import _aliases
+
+    for rows in (1, 7, 256):
+        host = np.zeros((rows, 1024), np.float32)
+        dev = jax.device_put(host)
+        dev.block_until_ready()
+        host[0, 0] = 1.0
+        shared = bool(np.asarray(dev)[0, 0] == 1.0)
+        assert _aliases(dev, host) == shared
+        assert not _aliases(dev + 0.0, host)
 
 
 def test_vfl_alignment_pairs_matching_ids(loader):

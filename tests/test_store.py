@@ -3,19 +3,23 @@
 Covers ``repro.data.store``: bit-exact shard round-trips (including
 zero-row modalities), the manifest-as-index contract (no file IO for row
 counts), ``FederatedBatcher.from_store`` batch streams bit-identical to
-the in-memory loader, the ``rows_for_clients`` multi-host seam, the
+the in-memory loader, shard maps held open within the descriptor
+budget, the ``rows_for_clients`` multi-host seam, the
 checkpoint store-fingerprint guard, store-backed resume parity, and the
 ``make docs-check`` reference checker."""
 import argparse
+import gc
 import os
+import resource
 import subprocess
 import sys
 
 import numpy as np
 import pytest
 
+from repro.data import store as store_mod
 from repro.data.pipeline import FederatedBatcher
-from repro.data.store import ClientStore, write_store
+from repro.data.store import ClientStore, ShardRows, write_store
 
 from test_federated_loader import _ragged_clients, _spec, _val
 
@@ -140,6 +144,97 @@ def test_from_store_batches_bit_identical(tmp_path, spec_kw):
     for k in ("val_a", "val_b", "val_y"):  # store-recorded val rides put()
         np.testing.assert_array_equal(np.asarray(mem._val[k]),
                                       np.asarray(sto._val[k]), err_msg=k)
+
+
+# ------------------------------------------------- shard maps held open ----
+
+def _open_fds() -> int:
+    return len(os.listdir("/proc/self/fd"))
+
+
+def test_shard_maps_open_once_per_shard_read(tmp_path, monkeypatch):
+    """Over a stream of rounds a store-backed batcher opens one map per
+    distinct shard it read, and a second pass over the same rounds opens
+    none."""
+    spec = _spec(n_clients=6, n_sampled=3)
+    rng = np.random.default_rng(12)
+    _, _, store = _make_store(tmp_path, spec, rng, zero_b_client=2)
+    read = set()
+    real = ShardRows._gather
+
+    def spy(self, fn):
+        if self._maps is store.maps:
+            read.add(self.path)
+        return real(self, fn)
+
+    monkeypatch.setattr(ShardRows, "_gather", spy)
+    b = FederatedBatcher.from_store(store, spec, seed=3)
+    for _ in b.rounds(0, 6, prefetch=1):
+        pass
+    assert 0 < b.map_opens == len(read) <= store.maps.n_shards
+    assert b.reads_unmapped == 0
+    opened = b.map_opens
+    for _ in b.rounds(0, 6, prefetch=1):
+        pass
+    assert b.map_opens == opened
+
+
+def test_reads_past_the_descriptor_budget_stay_unmapped(tmp_path,
+                                                        monkeypatch):
+    """With room for two maps the stream is unchanged: the other shards
+    are read by opening, gathering and closing, as before maps were
+    held."""
+    monkeypatch.setattr(store_mod, "_map_budget", lambda need: 2)
+    spec = _spec()
+    rng = np.random.default_rng(13)
+    clients, val, store = _make_store(tmp_path, spec, rng, zero_b_client=1)
+    mem = FederatedBatcher(clients, spec, val, seed=4)
+    sto = FederatedBatcher.from_store(store, spec, seed=4)
+    for r, dev in sto.rounds(0, 5, prefetch=1):
+        want = mem.build(r)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(dev[k]), want[k],
+                                          err_msg=f"round {r} key {k}")
+    assert sto.map_opens == 2 and sto.reads_unmapped > 0
+
+
+def test_map_budget_raises_the_soft_limit_only_as_far_as_needed():
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    low = _open_fds() + store_mod._FD_RESERVE + 10
+    need = 50
+    try:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (low, hard))
+        budget = store_mod._map_budget(need)
+        raised = resource.getrlimit(resource.RLIMIT_NOFILE)[0]
+    finally:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (soft, hard))
+    if hard == resource.RLIM_INFINITY or hard >= low + need:
+        assert budget == need
+        assert low < raised <= low + need  # toward the need, not the hard
+    else:
+        assert raised == hard and 0 <= budget < need
+
+
+def test_take_gathers_into_out_and_close_or_drop_releases_maps(tmp_path):
+    spec = _spec()
+    rng = np.random.default_rng(14)
+    clients, _, store = _make_store(tmp_path, spec, rng)
+    rows = store.client(1)["partial_a"]
+    src = clients[1]["partial_a"]
+    sel = rng.permutation(len(src))[: max(1, len(src) // 2)]
+    out = np.full((len(sel) + 2,) + src.shape[1:], np.nan, np.float32)
+    rows.take(sel, axis=0, out=out[: len(sel)], mode="clip")
+    np.testing.assert_array_equal(out[: len(sel)], src[sel])
+    assert np.isnan(out[len(sel):]).all()  # nothing past its block
+    assert store.maps.map_opens == 1
+    fds = _open_fds()
+    store.close()
+    assert _open_fds() == fds - 1
+    np.testing.assert_array_equal(rows[sel], src[sel])  # maps again
+    assert store.maps.map_opens == 2
+    del store, rows  # dropping the store releases its maps too
+    gc.collect()
+    assert _open_fds() == fds - 1
 
 
 def test_from_store_round_runs(tmp_path):
