@@ -2,6 +2,7 @@
 traffic files, the peaks table, the compile cache, and the result line."""
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -12,6 +13,7 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE = BENCH / ".cache"
+ENCODERS = BENCH / "encoders"
 
 
 class NoChip(RuntimeError):
@@ -55,6 +57,41 @@ def reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def encoder(cfg: dict):
+    """The module ``bench/encoders/<enc_type>.py`` of the configuration's
+    encoder f_m: its ``init``, ``apply``, ``forward_flops``,
+    ``train_flops`` and ``n_params``. A configuration that names no encoder,
+    as the hand-counted ones of ``test_bench_flops`` do, has the MLP. One
+    whose encoder has no module fails here, naming the missing file."""
+    name = cfg.get("enc_type", "mlp")
+    path = ENCODERS / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"encoder {name!r} has no module: {path} is missing")
+    return _load(str(path))
+
+
+def encoder_args(cfg: dict) -> dict:
+    """Every setting of the program's ``EncoderConfig`` that the
+    configuration sets, under the same key: an encoder's hyperparameters
+    reach the program as data, with no edit here for a new one."""
+    import dataclasses
+
+    from repro.core.encoders import EncoderConfig
+
+    return {f.name: cfg[f.name] for f in dataclasses.fields(EncoderConfig)
+            if f.name in cfg}
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: str):
+    name = "bench_encoder_" + Path(path).stem
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def peaks_for(kind: str) -> dict:

@@ -5,16 +5,12 @@ element-wise work (tanh, gelu, norms, losses, optimizer) is left out.
 A training row pays its forward products and, in the backward pass, the
 weight gradient of every product plus the input gradient of every product
 whose input is not data: the input layer of an encoder reads the raw
-features, so no gradient flows into them.
+features, so no gradient flows into them. The encoder's terms come from
+its module (``bench/encoders/<enc_type>.py``), counted by the same rule.
 """
 from __future__ import annotations
 
-
-def _enc(cfg: dict, side: str) -> tuple:
-    """(input-layer, hidden-layers) forward FLOPs of one encoder row."""
-    d = cfg["d_hidden"]
-    seq, feat = cfg[f"seq_{side}"], cfg[f"feat_{side}"]
-    return 2 * seq * feat * d, cfg["n_layers"] * 2 * d * d
+from bench.common import encoder
 
 
 def _head(cfg: dict) -> int:
@@ -28,7 +24,8 @@ def _fusion(cfg: dict) -> int:
 
 def forward_row(cfg: dict, route: str) -> int:
     """Forward FLOPs of one row on a serving route."""
-    enc_a, enc_b = sum(_enc(cfg, "a")), sum(_enc(cfg, "b"))
+    enc = encoder(cfg)
+    enc_a, enc_b = enc.forward_flops(cfg, "a"), enc.forward_flops(cfg, "b")
     if route == "unimodal_A":
         return enc_a + _head(cfg)
     if route == "unimodal_B":
@@ -38,16 +35,12 @@ def forward_row(cfg: dict, route: str) -> int:
     raise ValueError(f"unknown route {route!r}")
 
 
-def _train_enc(cfg: dict, side: str) -> int:
-    first, hidden = _enc(cfg, side)
-    return 2 * first + 3 * hidden  # fwd + weight grad; hidden adds input grad
-
-
 def n_params(cfg: dict) -> int:
     """Parameters of one client's model set {f_A, f_B, g_A, g_B, g_M}."""
-    d, out, n = cfg["d_hidden"], cfg["n_labels"], cfg["n_layers"]
-    enc = lambda f: f * d + d + n * (d * d + d) + d  # noqa: E731
-    return (enc(cfg["feat_a"]) + enc(cfg["feat_b"]) + 2 * (d * out + out)
+    d, out = cfg["d_hidden"], cfg["n_labels"]
+    enc = encoder(cfg)
+    return (enc.n_params(cfg, cfg["feat_a"]) + enc.n_params(cfg, cfg["feat_b"])
+            + 2 * (d * out + out)
             + (2 * d * d + d) + (d * out + out))
 
 
@@ -61,12 +54,14 @@ def train_round(cfg: dict, live: dict, n_val: int) -> int:
     pass of the blended encoders; the blend is one multiply-add per
     candidate and parameter."""
     C = len(live["paired"])
-    uni_a = _train_enc(cfg, "a") + 3 * _head(cfg)
-    uni_b = _train_enc(cfg, "b") + 3 * _head(cfg)
-    both = _train_enc(cfg, "a") + _train_enc(cfg, "b") + 3 * _fusion(cfg)
+    enc = encoder(cfg)
+    tr_a, tr_b = enc.train_flops(cfg, "a"), enc.train_flops(cfg, "b")
+    uni_a = tr_a + 3 * _head(cfg)
+    uni_b = tr_b + 3 * _head(cfg)
+    both = tr_a + tr_b + 3 * _fusion(cfg)
     train = (sum(live["partial_a"]) * uni_a + sum(live["partial_b"]) * uni_b
              + (sum(live["paired"]) + live["aligned"]) * both)
-    enc_a, enc_b = sum(_enc(cfg, "a")), sum(_enc(cfg, "b"))
+    enc_a, enc_b = enc.forward_flops(cfg, "a"), enc.forward_flops(cfg, "b")
     score = n_val * ((C + 1) * (enc_a + _head(cfg) + enc_b + _head(cfg))
                      + enc_a + enc_b + (C + 2) * _fusion(cfg))
     blend = 2 * (C + 1) * n_params(cfg)

@@ -4,8 +4,9 @@ Written from the method's description (BlendFL, arXiv:2510.13266, Alg. 1)
 and the configuration files, in straightforward ``jax.numpy``; it imports
 nothing of the program. Its parts:
 
-- encoder f_m: x (B, S, F) -> tanh(x W_in + b) averaged over S, then
-  ``n_layers`` residual blocks h + gelu(h W + b), then RMS norm (eps 1e-5);
+- encoder f_m: x (B, S, F) -> h (B, d), the configuration's encoder, whose
+  weights and equations its module ``bench/encoders/<enc_type>.py`` holds
+  (``common.encoder``);
 - unimodal head g_m: h W + b; fusion head g_M: gelu([h_A, h_B] W + b) W' + b';
 - task loss: mean sigmoid cross-entropy over labels (multilabel) or softmax
   cross-entropy (multiclass), a masked mean over live rows;
@@ -25,11 +26,12 @@ every backend computes the same thing) or ``bf16`` (one pass).
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
+
+from bench.common import encoder as encoder_module
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
@@ -68,12 +70,9 @@ def linear(p, x, prec):
     return mm(x, p["w"], prec) + p["b"]
 
 
-def encoder(p, x, prec):
-    h = jnp.mean(jnp.tanh(linear(p["in"], x, prec)), axis=-2)
-    for layer in p["hidden"]:
-        h = h + gelu(linear(layer, h, prec))
-    return h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + 1e-5) \
-        * p["norm"]["g"]
+def encode(cfg: dict, p, x, prec):
+    """f_m(x): (B, S, F) -> (B, d) by the configuration's encoder."""
+    return encoder_module(cfg).apply(p, x, cfg, prec)
 
 
 def fusion(p, h_a, h_b, prec):
@@ -103,33 +102,27 @@ def probs(logits, kind):
 
 # ------------------------------------------------------------------ init --
 
-def _dense(key, d_in, d_out):
+def dense_init(key, d_in, d_out):
     return {"w": jax.random.normal(key, (d_in, d_out)) * (1.0 / jnp.sqrt(d_in)),
             "b": jnp.zeros((d_out,), jnp.float32)}
 
 
-def _encoder_init(key, feat, d, n_layers):
-    ks = jax.random.split(key, n_layers + 2)
-    return {"in": _dense(ks[0], feat, d),
-            "hidden": [_dense(ks[i + 1], d, d) for i in range(n_layers)],
-            "norm": {"g": jnp.ones((d,), jnp.float32)}}
-
-
 def fusion_init(key, d, out):
     k1, k2 = jax.random.split(key)
-    return {"mix": _dense(k1, 2 * d, d), "out": _dense(k2, d, out)}
+    return {"mix": dense_init(k1, 2 * d, d), "out": dense_init(k2, d, out)}
 
 
 def init_models(key, cfg: dict) -> dict:
     """{f_A, f_B, g_A, g_B, g_M} from one key: split into five, in that
-    order f_A, f_B, g_A, g_B, g_M; an encoder splits its key into
-    n_layers + 2 (input layer, hidden layers); the fusion head into two
-    (mix, out); each weight is N(0, 1/d_in), each bias and norm gain 0/1."""
-    d, n, out = cfg["d_hidden"], cfg["n_layers"], cfg["n_labels"]
+    order f_A, f_B, g_A, g_B, g_M; an encoder uses its key as its module's
+    ``init`` documents; the fusion head splits its key into two (mix,
+    out); each head weight is N(0, 1/d_in), each head bias 0."""
+    d, out = cfg["d_hidden"], cfg["n_labels"]
+    enc = encoder_module(cfg)
     ks = jax.random.split(key, 5)
-    return {"f_A": _encoder_init(ks[0], cfg["feat_a"], d, n),
-            "f_B": _encoder_init(ks[1], cfg["feat_b"], d, n),
-            "g_A": _dense(ks[2], d, out), "g_B": _dense(ks[3], d, out),
+    return {"f_A": enc.init(ks[0], cfg["feat_a"], cfg),
+            "f_B": enc.init(ks[1], cfg["feat_b"], cfg),
+            "g_A": dense_init(ks[2], d, out), "g_B": dense_init(ks[3], d, out),
             "g_M": fusion_init(ks[4], d, out)}
 
 
@@ -164,12 +157,14 @@ def make_round(cfg: dict, precision: str):
     kind, lr = cfg["kind"], cfg["lr"]
     prec = precision
 
+    def encoder(f, x):
+        return encode(cfg, f, x, prec)
+
     def uni_loss(f, g, x, y, m):
-        return masked_mean(row_loss(linear(g, encoder(f, x, prec), prec), y,
-                                    kind), m)
+        return masked_mean(row_loss(linear(g, encoder(f, x), prec), y, kind), m)
 
     def pair_loss(fa, fb, gm, xa, xb, y, m):
-        logits = fusion(gm, encoder(fa, xa, prec), encoder(fb, xb, prec), prec)
+        logits = fusion(gm, encoder(fa, xa), encoder(fb, xb), prec)
         return masked_mean(row_loss(logits, y, kind), m)
 
     def step_groups(st, grads, names, flags):
@@ -203,8 +198,8 @@ def make_round(cfg: dict, precision: str):
         d = cfg["d_hidden"]
 
         def joint(p, gmv):
-            ha = jax.vmap(lambda f, x: encoder(f, x, prec))(p["f_A"], b["frag_a"])
-            hb = jax.vmap(lambda f, x: encoder(f, x, prec))(p["f_B"], b["frag_b"])
+            ha = jax.vmap(encoder)(p["f_A"], b["frag_a"])
+            hb = jax.vmap(encoder)(p["f_B"], b["frag_b"])
             ha = ha.reshape(-1, d)
             hb = hb.reshape(-1, d)[b["perm_b"]]
             y = b["frag_y"].reshape(ha.shape[0], -1)
@@ -281,7 +276,7 @@ def train_rounds(cfg: dict, batches: list, val: dict, seed: int,
     vy = jnp.asarray(val["val_y"])
     va, vb = jnp.asarray(val["val_a"]), jnp.asarray(val["val_b"])
     uni = jax.jit(lambda f, g, x: -uni_loss(f, g, x, vy, ones))
-    enc = jax.jit(lambda f, x: encoder(f, x, precision))
+    enc = jax.jit(lambda f, x: encode(cfg, f, x, precision))
     kind = cfg["kind"]
     multi = jax.jit(lambda gm, ha, hb: -masked_mean(
         row_loss(fusion(gm, ha, hb, precision), vy, kind), ones))
@@ -336,23 +331,26 @@ def codec_roundtrip(x, frac: float, quantize: bool = True):
     return jnp.where(keep, x, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("route", "kind", "precision",
-                                             "frac"))
-def serve_scores(models, server, x_a, x_b, *, route: str, kind: str,
-                 precision: str, frac: float):
-    """Scores of one request's rows on one route."""
-    p = precision
-    if route == "unimodal_A":
-        return probs(linear(models["g_A"], encoder(models["f_A"], x_a, p), p),
-                     kind)
-    if route == "unimodal_B":
-        return probs(linear(models["g_B"], encoder(models["f_B"], x_b, p), p),
-                     kind)
-    ha, hb = encoder(models["f_A"], x_a, p), encoder(models["f_B"], x_b, p)
-    if route == "multimodal":
-        return probs(fusion(models["g_M"], ha, hb, p), kind)
-    ha, hb = codec_roundtrip(ha, frac), codec_roundtrip(hb, frac)
-    return codec_roundtrip(probs(fusion(server, ha, hb, p), kind), frac)
+def serve_scores(cfg: dict, route: str, precision: str, frac: float):
+    """``scores(models, server, x_a, x_b)``, jitted: the scores of one
+    request's rows on one route."""
+    kind = cfg["kind"]
+
+    def enc(f, x):
+        return encode(cfg, f, x, precision)
+
+    def scores(models, server, x_a, x_b):
+        p = precision
+        if route == "unimodal_A":
+            return probs(linear(models["g_A"], enc(models["f_A"], x_a), p), kind)
+        if route == "unimodal_B":
+            return probs(linear(models["g_B"], enc(models["f_B"], x_b), p), kind)
+        ha, hb = enc(models["f_A"], x_a), enc(models["f_B"], x_b)
+        if route == "multimodal":
+            return probs(fusion(models["g_M"], ha, hb, p), kind)
+        ha, hb = codec_roundtrip(ha, frac), codec_roundtrip(hb, frac)
+        return codec_roundtrip(probs(fusion(server, ha, hb, p), kind), frac)
+    return jax.jit(scores)
 
 
 def serve_models(seed: int, cfg: dict):

@@ -36,6 +36,7 @@ def main(argv=None) -> int:
 
     info = common.resolve(args.workload)
     cfg, traffic = info["config"], info["traffic"]
+    common.encoder(cfg)  # a configuration's encoder without a module fails here
     import jax
 
     common.use_compile_cache()
@@ -63,7 +64,8 @@ def main(argv=None) -> int:
           f"{run['check_s']:.3f} s",
           file=sys.stderr)
     print("bench: host-clock readings " + json.dumps(out["e2e"]), file=sys.stderr)
-    for key in ("round_ms", "probe", "trace_read_s", "grad_worst"):
+    for key in ("round_ms", "probe", "trace_read_s", "op_scopes_s",
+                "op_scopes_pct", "grad_worst"):
         if key in run:
             print(f"bench: {key} " + json.dumps(run[key]), file=sys.stderr)
     correct, compared = common.compare(out["readings"], info["limits"])

@@ -25,7 +25,7 @@ import time
 import numpy as np
 
 from bench import checks, datastore, refdata, reference, traces, traffic_gen
-from bench.common import annotate, device_info
+from bench.common import annotate, device_info, encoder_args
 from bench.flops import forward_row
 
 
@@ -52,8 +52,7 @@ def setup(cfg: dict, traffic: dict, seed: int, devs: list, cache_dir):
     pool = refdata.StoreFiles(store_dir).val()
     tspec = TaskSpec(cfg["task"], cfg["kind"], cfg["n_labels"], cfg["seq_a"],
                      cfg["feat_a"], cfg["seq_b"], cfg["feat_b"])
-    ecfg = EncoderConfig(d_hidden=cfg["d_hidden"], n_layers=cfg["n_layers"],
-                         enc_type=cfg["enc_type"])
+    ecfg = EncoderConfig(**encoder_args(cfg))
     k_models, k_server = jax.random.split(reference.seed_key(seed))
     with jax.default_device(devs[0]):
         models = jax.jit(lambda k: init_client_models(k, tspec, ecfg))(k_models)
@@ -108,13 +107,13 @@ def reference_scores(cfg, traffic, pool, sched, picks, seed,
         rows = np.concatenate(sl)
         n = len(rows)
         pad = np.concatenate([rows, np.zeros((-n) % block, np.int64)])
+        scores = reference.serve_scores(cfg, route, precision,
+                                        traffic["topk_frac"])
         parts = []
         for s in range(0, len(pad), block):
             sel = pad[s:s + block]
-            parts.append(np.asarray(reference.serve_scores(
-                models, server, pool["val_a"][sel], pool["val_b"][sel],
-                route=route, kind=cfg["kind"], precision=precision,
-                frac=traffic["topk_frac"])))
+            parts.append(np.asarray(scores(models, server, pool["val_a"][sel],
+                                           pool["val_b"][sel])))
         out[route] = np.concatenate(parts)[:n]
     return out
 

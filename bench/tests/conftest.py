@@ -37,11 +37,12 @@ def RESOLVE(cell: str) -> dict:
     return _resolve(cell, spec=with_pending())
 
 
-def tiny(cell: str) -> dict:
+def tiny(cell: str, **config) -> dict:
+    """The cell at a tiny size, with ``config`` over its configuration."""
     info = RESOLVE(cell)
     info["config"] = dict(info["config"], seq_a=6, feat_a=5, seq_b=4, feat_b=8,
                           d_hidden=16, n_clients=4, rows_cap=8, n_train=256,
-                          n_val=128)
+                          n_val=128, **config)
     if info["traffic"]["kind"] == "serve":
         info["traffic"] = dict(info["traffic"], capacities=[2, 4, 16],
                                rate_per_s=150.0,
@@ -56,15 +57,16 @@ def cache(tmp_path_factory):
 
 @pytest.fixture
 def harness(monkeypatch, cache, capsys):
-    """run.main(argv) with tiny files and CPU devices -> (rc, result)."""
+    """run.main(argv) with tiny files and CPU devices -> (rc, result);
+    ``config`` goes over the tiny configuration."""
     prec = jax.config.jax_default_matmul_precision
     monkeypatch.setattr(common, "devices", lambda chips: jax.devices()[:chips])
     monkeypatch.setattr(common, "peaks_for", lambda kind: V5E)
     monkeypatch.setattr(common, "use_compile_cache", lambda: None)
     monkeypatch.setattr(common, "CACHE", cache)
 
-    def go(cell, trace=0):
-        monkeypatch.setattr(common, "resolve", lambda w: tiny(cell))
+    def go(cell, trace=0, **config):
+        monkeypatch.setattr(common, "resolve", lambda w: tiny(cell, **config))
         capsys.readouterr()
         rc = run.main(["--workload", cell, "--seed", str(SEED), "--seconds",
                        "1", "--trace", str(trace)])

@@ -5,6 +5,7 @@ import pytest
 
 @pytest.mark.parametrize("cell,trace", [("mimic_cxr.train", 0),
                                         ("mimic_cxr.train", 1),
+                                        ("uci_har.train", 1),
                                         ("mimic_cxr.serve", 0)])
 def test_program_agrees_with_reference(harness, cell, trace):
     rc, res = harness(cell, trace)

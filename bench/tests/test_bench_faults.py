@@ -43,6 +43,7 @@ def _answer_altered(monkeypatch):
 
 @pytest.mark.parametrize("cell,fault", [
     ("mimic_cxr.train", _state_unchanged), ("mimic_cxr.train", _half_batch),
+    ("uci_har.train", _state_unchanged), ("uci_har.train", _half_batch),
     ("mimic_cxr.serve", _answer_altered)])
 def test_a_broken_timed_path_is_not_correct(harness, monkeypatch, cell, fault):
     fault(monkeypatch)

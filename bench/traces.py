@@ -8,8 +8,10 @@ program (``jax.profiler.TraceAnnotation``s named ``bench.*`` or after the
 layer they enter). ``window`` clips both to the ``bench.window`` span; the
 per-layer readers (``bench/metrics``) take their kernel, collective or
 phase times from those clipped lists, and ``op_shape`` reads an op's
-output shape from its label. ``summarize`` needs nothing else, so the
-tests feed it synthetic events.
+output shape from its label. The trace carries no op's named scope:
+``op_scopes`` reads them from the compiled module's HLO text, and
+``scope_of`` looks a label up there. ``summarize`` needs nothing else, so
+the tests feed it synthetic events.
 """
 from __future__ import annotations
 
@@ -56,6 +58,42 @@ def op_shape(label: str) -> tuple:
 
 
 _TYPE = re.compile(r" ([a-z0-9]+)\[([0-9,]*)\]$")
+
+
+def op_scopes(hlo_text: str, labels) -> dict:
+    """``{"module:instruction": scope}`` for the op of each ``op_label`` in
+    ``labels``, from a compiled module's HLO text. The scope is the
+    ``jax.named_scope`` path in the instruction's ``op_name`` metadata,
+    with the enclosing jits (``jit(round_fn)``), the transforms
+    (``jvp(...)``, ``vmap()``) and the primitive left out: ``aggregate/score``
+    for ``jit(round_fn)/aggregate/score/vmap()/dot_general``. An op with no
+    metadata, no scope in it, or from another module maps to None."""
+    head = re.search(r"^HloModule ([^\s,]+)", hlo_text, re.M)
+    module = head.group(1) if head else "?"
+    found = {f"{module}:{m.group(1)}": _scope(m.group(2))
+             for m in _OP_NAME.finditer(hlo_text)}
+    return {h: found.get(h) for h in {label.split(" ", 1)[0] for label in labels}}
+
+
+_OP_NAME = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*?"
+                      r"metadata=\{[^}\n]*?op_name=\"([^\"]*)\"", re.M)
+
+
+def _scope(op_name: str):
+    parts, depth, cur = [], 0, ""
+    for ch in op_name:  # split on the slashes outside parentheses
+        depth += (ch == "(") - (ch == ")")
+        if ch == "/" and depth == 0:
+            parts.append(cur)
+            cur = ""
+        else:
+            cur += ch
+    return "/".join(p for p in parts if "(" not in p) or None
+
+
+def scope_of(label: str, scopes: dict):
+    """The named scope ``op_scopes`` gave the op of ``label``, or None."""
+    return scopes.get(label.split(" ", 1)[0])
 
 
 def _device_events(plane) -> list:

@@ -6,7 +6,8 @@ makes the round state from the seed in one jitted call, and hands both to
 ``run``, which compiles the round and drives the first ``setup_rounds``
 rounds (the rounds the reference follows). The window is the same ``run``
 call going on: it opens when those rounds are done and closes at the first
-round that ends ``--seconds`` after it opened.
+round that ends ``--seconds`` after it opened. A traced run then maps each
+device op of the window to its named scope (``op_scopes``) for the readers.
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ import time
 import numpy as np
 
 from bench import checks, datastore, refdata, reference, traces
-from bench.common import annotate, device_info
+from bench.common import annotate, device_info, encoder_args
 from bench.flops import train_round
 
 
@@ -31,13 +32,12 @@ class WindowClosed(Exception):
 def _args(cfg: dict, traffic: dict, store_dir: str, seed: int):
     return argparse.Namespace(
         store_dir=store_dir, scenario=None, rows_cap=cfg["rows_cap"],
-        d_hidden=cfg["d_hidden"], n_layers=cfg["n_layers"], lr=cfg["lr"],
-        optimizer=cfg["optimizer"], n_sampled=traffic["n_sampled"],
+        lr=cfg["lr"], optimizer=cfg["optimizer"], n_sampled=traffic["n_sampled"],
         policy=traffic["policy"], codec=traffic["codec"],
         topk_frac=traffic["topk_frac"], strategy=traffic["strategy"],
         fedprox_mu=0.0, server_opt="none", server_lr=1.0, n_malicious=1,
         seed=seed, prefetch=traffic["prefetch"], rounds=1 << 30,
-        ckpt_dir=None, ckpt_every=0, log_every=1)
+        ckpt_dir=None, ckpt_every=0, log_every=1, **encoder_args(cfg))
 
 
 def setup(cfg: dict, traffic: dict, seed: int, devs: list, cache_dir):
@@ -49,10 +49,43 @@ def setup(cfg: dict, traffic: dict, seed: int, devs: list, cache_dir):
     store_dir = datastore.ensure(cfg, cache_dir)
     spec, batcher, round_fn, mesh = tf.build_federation(
         _args(cfg, traffic, store_dir, seed), devices=devs)
+    for key, want in encoder_args(cfg).items():
+        if getattr(spec.ecfg, key) != want:
+            raise ValueError(
+                f"the configuration sets the encoder's {key} to {want!r}, "
+                f"the program built {getattr(spec.ecfg, key)!r}")
     state = tf.place_state(
         jax.jit(init_round_state, static_argnums=1)(reference.seed_key(seed),
                                                      spec), mesh)
     return store_dir, spec, batcher, round_fn, state
+
+
+def op_scopes(round_fn, args, labels) -> dict:
+    """``traces.op_scopes`` of the ops ``labels`` names, from the text of
+    ``round_fn`` compiled afresh at ``args``' shapes. The persistent compile
+    cache is off for that compile: its key leaves metadata out, so a cached
+    executable can carry another build's scopes. A compiler option set to
+    its default keeps JAX's in-memory caches from handing back the window's
+    executable instead of compiling."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = round_fn.lower(*args).compile({"xla_dump_to": ""}).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    return traces.op_scopes(text, labels)
+
+
+def _abstract(x):
+    import jax
+
+    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                sharding=getattr(x, "sharding", None))
 
 
 def program_readings(kept: dict, n: int) -> dict:
@@ -112,6 +145,8 @@ def drive(cfg, traffic, seed, seconds, trace, devs, clock, cache_dir):
     def round_call(st, batch):
         if done[0] == 0:
             kept[0] = st
+            if trace:
+                win["args"] = jax.tree.map(_abstract, (st, batch))
         with jax.profiler.TraceAnnotation("bench.round_fn"):
             out = round_fn(st, batch)
         done[0] += 1
@@ -165,6 +200,20 @@ def drive(cfg, traffic, seed, seconds, trace, devs, clock, cache_dir):
     rounds = done[0] - n_setup
     window_s = win["t1"] - win["t0"]
     prog = win["prog"]
+    run = {}
+    if trace:
+        run.update(traces.reduce(win["dir"]))
+        shutil.rmtree(win["dir"], ignore_errors=True)
+        t_scopes = time.perf_counter()
+        evs = [ev for plane in run["ops"].values() for ev in plane]
+        run["op_scopes"] = op_scopes(round_fn, win["args"],
+                                     [n for n, _, _ in evs])
+        run["op_scopes_s"] = time.perf_counter() - t_scopes
+        if evs:  # the share of op time that has a scope
+            run["op_scopes_pct"] = 100 * sum(
+                e - s for n, s, e in evs
+                if traces.scope_of(n, run["op_scopes"])) / sum(
+                e - s for _, s, e in evs)
     del batcher, round_fn, spec
     gc.collect()
     t_check = time.perf_counter()
@@ -173,23 +222,21 @@ def drive(cfg, traffic, seed, seconds, trace, devs, clock, cache_dir):
     grad_worst = checks.worst_grad_leaves(prog, ref)
     check_s = time.perf_counter() - t_check
 
-    run = {"window_s": window_s, "rounds": rounds, "setup_s": win["setup_s"],
-           "compiles_in_window": len(compiles), "check_s": check_s,
-           "grad_worst": grad_worst,
-           "build_s": win["c1"][0] - win["c0"][0],
-           "stall_s": win["c1"][1] - win["c0"][1],
-           "built": win["c1"][2] - win["c0"][2],
-           "round_ms": [round(b * 1e3, 1) for b in
-                        np.diff([win["t0"]] + win["ends"])]}
+    run.update(window_s=window_s, rounds=rounds, setup_s=win["setup_s"],
+               compiles_in_window=len(compiles), check_s=check_s,
+               grad_worst=grad_worst,
+               build_s=win["c1"][0] - win["c0"][0],
+               stall_s=win["c1"][1] - win["c0"][1],
+               built=win["c1"][2] - win["c0"][2],
+               round_ms=[round(b * 1e3, 1) for b in
+                         np.diff([win["t0"]] + win["ends"])])
     if trace:
-        run.update(traces.reduce(win["dir"]))
         store = refdata.StoreFiles(store_dir)
         dims = _dims(cfg)
         flops = [train_round(cfg, refdata.live_rows(refdata.round_batch(
             store, dims, seed, r, with_x=False)), cfg["n_val"])
             for r in range(n_setup, n_setup + rounds)]
         run["flops_per_round"] = float(np.mean(flops))
-        shutil.rmtree(win["dir"], ignore_errors=True)
     return {"device": device, "readings": readings, "run": run,
             "attempted": rounds, "failed": 0,
             "e2e": {"setup_s": win["setup_s"], "round_s": window_s / rounds}}
